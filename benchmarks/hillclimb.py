@@ -1,5 +1,6 @@
 import os
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
+os.environ["JAX_PLATFORMS"] = "cpu"     # CPU-only: 512 virtual host devices
 
 """Perf hillclimb driver (EXPERIMENTS.md §Perf).
 

@@ -66,6 +66,8 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     all_rows: list[str] = []
     for name, (mod, fn, kw) in SUITES.items():

@@ -1241,6 +1241,8 @@ def sharded_worker(data: int, model: int) -> None:
 
 def _run_shard_worker(data: int, model: int) -> dict:
     env = dict(os.environ)
+    # virtual host devices: the worker must never claim an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
                         f"{data * model}")
     r = subprocess.run(

@@ -71,7 +71,9 @@ class ArchConfig:
     dtype: str = "bfloat16"
     remat: bool = True
     use_scan: bool = True
-    use_pallas: bool = False       # kernels are TPU-target; dry-run uses XLA path
+    use_pallas: bool = False       # full-sequence forwards: flash attention
+                                   # and the SSD scan kernel (the paged
+                                   # serving path picks by backend)
 
     # ----- derived -----
     @property

@@ -127,40 +127,10 @@ class CompGraph:
         return [env[o.uid] for o in self.outputs], captured
 
 
-try:                # feature-detect once: kwargs exist on newer JAX only
-    jtu.keystr((), simple=True, separator=".")
-    _KEYSTR_HAS_KWARGS = True
-except TypeError:   # pragma: no cover - version dependent
-    _KEYSTR_HAS_KWARGS = False
-
-
 def keystr(path) -> str:
-    """Dotted pytree path ("layers.attn.wq") across JAX versions.
-
-    ``jtu.keystr(..., simple=True, separator=".")`` only exists on newer
-    JAX; on 0.4.x we join the key entries by hand.  Called in flatten
-    loops over every leaf, so the capability is probed at import, not
-    per call.
-    """
-    if _KEYSTR_HAS_KWARGS:
-        return jtu.keystr(path, simple=True, separator=".")
-    parts = []
-    for k in path:
-        if isinstance(k, jtu.DictKey):
-            parts.append(str(k.key))
-        elif isinstance(k, jtu.SequenceKey):
-            parts.append(str(k.idx))
-        elif isinstance(k, jtu.GetAttrKey):
-            parts.append(str(k.name))
-        elif isinstance(k, jtu.FlattenedIndexKey):
-            parts.append(str(k.key))
-        else:  # unknown entry type: strip the repr's decoration
-            parts.append(str(k).strip(".[]'\""))
-    return ".".join(parts)
-
-
-def _path_str(path) -> str:
-    return keystr(path)
+    """Dotted pytree path ("layers.attn.wq") — the key format every
+    pruning, importance and checkpoint table is indexed by."""
+    return jtu.keystr(path, simple=True, separator=".")
 
 
 def trace_graph(fn: Callable, params, *args) -> CompGraph:
@@ -173,7 +143,7 @@ def trace_graph(fn: Callable, params, *args) -> CompGraph:
     g = CompGraph()
 
     flat_params, _ = jtu.tree_flatten_with_path(params)
-    param_paths = [_path_str(p) for p, _ in flat_params]
+    param_paths = [keystr(p) for p, _ in flat_params]
     n_params = len(flat_params)
 
     var_map: dict[Any, DataNode] = {}

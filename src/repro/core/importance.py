@@ -34,34 +34,36 @@ def hessian_grad_product(loss_fn, params, *args):
 
 
 def leaf_scores(params, criterion: str, grads=None, hg=None, seed: int = 0):
-    """Per-weight importance S(θ) as an f32 pytree."""
-    f32 = lambda t: jax.tree.map(lambda x: x.astype(jnp.float32), t)
-    p = f32(params)
+    """Per-weight importance S(θ) as a pytree of f32 host arrays.
+
+    Scored one leaf at a time and moved to the host at once: an f32 copy
+    of a bf16 model is twice its size, and holding it whole on the device
+    next to the weights would not fit a chip that holds the model."""
+    f32 = lambda x: x.astype(jnp.float32)
+    extra: tuple = ()
     if criterion in ("l1", "magnitude"):
-        return jax.tree.map(jnp.abs, p)
-    if criterion == "l2":
-        return jax.tree.map(jnp.square, p)
-    if criterion == "snip":
+        fn = lambda t: jnp.abs(f32(t))
+    elif criterion == "l2":
+        fn = lambda t: jnp.square(f32(t))
+    elif criterion == "snip":
         assert grads is not None, "snip needs grads"
-        return jax.tree.map(lambda t, g: jnp.abs(t * g.astype(jnp.float32)),
-                            p, grads)
-    if criterion == "grasp":
+        fn, extra = (lambda t, g: jnp.abs(f32(t) * f32(g))), (grads,)
+    elif criterion == "grasp":
         assert hg is not None, "grasp needs Hg"
         # lower score = better to KEEP removing? GraSP scores: -θ·Hg; we prune
         # the *lowest* importance, so negate to match "high = keep".
-        return jax.tree.map(lambda t, h: -(t * h.astype(jnp.float32)), p, hg)
-    if criterion == "crop":
+        fn, extra = (lambda t, h: -(f32(t) * f32(h))), (hg,)
+    elif criterion == "crop":
         assert hg is not None, "crop needs Hg"
-        return jax.tree.map(lambda t, h: jnp.abs(t * h.astype(jnp.float32)),
-                            p, hg)
-    if criterion == "random":
-        leaves, treedef = jtu.tree_flatten(p)
-        key = jax.random.PRNGKey(seed)
-        keys = jax.random.split(key, len(leaves))
-        return jtu.tree_unflatten(
-            treedef, [jax.random.uniform(k, l.shape) for k, l in
-                      zip(keys, leaves)])
-    raise ValueError(f"unknown criterion {criterion!r}")
+        fn, extra = (lambda t, h: jnp.abs(f32(t) * f32(h))), (hg,)
+    elif criterion == "random":
+        leaves, treedef = jtu.tree_flatten(params)
+        keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
+        fn = lambda t, k: jax.random.uniform(k, t.shape)
+        extra = (jtu.tree_unflatten(treedef, list(keys)),)
+    else:
+        raise ValueError(f"unknown criterion {criterion!r}")
+    return jax.tree.map(lambda *xs: np.asarray(fn(*xs)), params, *extra)
 
 
 def _axis_scores(leaf: np.ndarray, axis: int) -> np.ndarray:

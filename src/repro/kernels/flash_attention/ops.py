@@ -14,16 +14,15 @@ def _on_tpu() -> bool:
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     scale: float | None = None, block_q: int = 128,
-                    block_k: int = 128, interpret: bool | None = None):
-    """q (B, Sq, H, D); k/v (B, Sk, KH, D/DV) -> (B, Sq, H, DV)."""
-    if interpret is None:
-        interpret = not _on_tpu()
+                    block_k: int = 128):
+    """q (B, Sq, H, D); k/v (B, Sk, KH, D/DV) -> (B, Sq, H, DV).  Compiled
+    on the TPU, interpreted elsewhere."""
     qt = jnp.transpose(q, (0, 2, 1, 3))
     kt = jnp.transpose(k, (0, 2, 1, 3))
     vt = jnp.transpose(v, (0, 2, 1, 3))
     ot = flash_attention_bhsd(qt, kt, vt, causal=causal, window=window,
                               scale=scale, block_q=block_q, block_k=block_k,
-                              interpret=interpret)
+                              interpret=not _on_tpu())
     return jnp.transpose(ot, (0, 2, 1, 3))
 
 

@@ -25,20 +25,21 @@ from jax.experimental import pallas as pl
 
 def _inblock_kernel(w_ref, h_ref, m_ref, wout_ref, e_ref, *, block: int):
     w = w_ref[...].astype(jnp.float32)          # (BR, B)
-    h = h_ref[...].astype(jnp.float32)          # (B, B)
     m = m_ref[...].astype(jnp.float32)          # (1, B)
     cols = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
 
     def body(j, carry):
+        # column j of a value is read as a one-hot reduction (exact: every
+        # other term is a zero) — the TPU lowers no dynamic lane slice
         w, e = carry
-        hrow = jax.lax.dynamic_slice_in_dim(h, j, 1, axis=0)      # (1, B)
-        hjj = jax.lax.dynamic_slice_in_dim(hrow, j, 1, axis=1)    # (1, 1)
-        wj = jax.lax.dynamic_slice_in_dim(w, j, 1, axis=1)        # (BR, 1)
+        onehot = (cols == j).astype(jnp.float32)                 # (1, B)
+        hrow = h_ref[pl.ds(j, 1), :].astype(jnp.float32)         # (1, B)
+        hjj = jnp.sum(hrow * onehot, axis=1, keepdims=True)      # (1, 1)
+        wj = jnp.sum(w * onehot, axis=1, keepdims=True)          # (BR, 1)
+        pj = jnp.sum(m * onehot, axis=1, keepdims=True)          # (1, 1)
         err = wj / hjj
-        pj = jax.lax.dynamic_slice_in_dim(m, j, 1, axis=1)        # (1, 1)
-        upd = (err * pj) * jnp.where(cols >= j, hrow, 0.0)        # (BR, B)
+        upd = (err * pj) * jnp.where(cols >= j, hrow, 0.0)       # (BR, B)
         w = w - upd
-        onehot = (cols == j).astype(jnp.float32)
         e = e + (err * pj) * onehot
         return w, e
 
@@ -80,5 +81,6 @@ def inblock_sweep(w: jax.Array, hinv_bb: jax.Array, mask: jax.Array,
             jax.ShapeDtypeStruct((Rp, B), jnp.float32),
         ],
         interpret=interpret,
+        name="obspa_inblock_sweep",
     )(w.astype(jnp.float32), hinv_bb.astype(jnp.float32), m2)
     return wout[:R], e[:R]

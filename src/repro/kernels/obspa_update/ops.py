@@ -21,11 +21,10 @@ def _on_tpu() -> bool:
 
 
 def obspa_sweep(W: jax.Array, Hinv: jax.Array, prune_mask: jax.Array,
-                col_block: int = 128, row_block: int = 256,
-                interpret: bool | None = None) -> jax.Array:
-    """Blocked OBSPA reconstruction.  W (R, K), Hinv (K, K), mask (K,)."""
-    if interpret is None:
-        interpret = not _on_tpu()
+                col_block: int = 128, row_block: int = 256) -> jax.Array:
+    """Blocked OBSPA reconstruction.  W (R, K), Hinv (K, K), mask (K,).
+    The in-block kernel is compiled on the TPU, interpreted elsewhere."""
+    interpret = not _on_tpu()
     W = jnp.asarray(W, jnp.float32)
     Hinv = jnp.asarray(Hinv, jnp.float32)
     mask = jnp.asarray(prune_mask, bool)
@@ -47,7 +46,11 @@ def obspa_sweep(W: jax.Array, Hinv: jax.Array, prune_mask: jax.Array,
         W = W.at[:, sl].set(w_blk)
         if b0 + col_block < Kp:
             panel = Hinv[sl, b0 + col_block:]           # (B, rest)
-            W = W.at[:, b0 + col_block:].add(-e_blk @ panel)
+            # full f32 passes: at default precision the TPU multiplies f32
+            # in one bf16 pass, and the compensation error compounds
+            # across column blocks
+            W = W.at[:, b0 + col_block:].add(-jnp.matmul(
+                e_blk, panel, precision=jax.lax.Precision.HIGHEST))
     return W[:, :K] if pad else W
 
 

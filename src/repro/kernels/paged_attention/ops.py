@@ -1,9 +1,12 @@
 """Dispatch for paged attention: Pallas kernel vs jnp reference.
 
-The kernel requires a *static* python-int window (mask folded into the
-kernel at trace time); a per-sequence dynamic window (Hymba hybrid layers,
-where the window is data under ``lax.scan``) falls back to the reference
-path, which takes window as an array.
+The backend chooses.  On the TPU the compiled kernel serves every call
+whose window is a *static* python int (mask folded into the kernel at
+trace time).  A per-sequence dynamic window (Hymba hybrid layers, where
+the window is data under ``lax.scan``) and every call on another backend
+take the reference, which takes window as an array and is the test
+oracle.  ``use_kernel=True`` forces the kernel, which then runs in
+interpret mode off the TPU — that is how tests exercise it on the CPU.
 
 ``return_visits`` exposes the kernel's per-(seq, kv-head) block-visit
 counter (the fully-masked-block skip's observable); it is kernel-only —
@@ -29,7 +32,6 @@ import functools
 import math
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import active_mesh, active_rules
@@ -41,6 +43,12 @@ from repro.kernels.paged_attention.ref import (
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def _kernel_path(window) -> bool:
+    """Whether the compiled kernel serves this call: on the TPU, for a
+    static window.  Tests that must steer the choice patch this."""
+    return _on_tpu() and isinstance(window, int)
 
 
 def _positional_scales(fn):
@@ -85,36 +93,36 @@ def _serve_partition(B: int, H: int, KH: int):
 
 def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
                     window=0, scale: float | None = None,
-                    use_kernel: bool = True, interpret: bool | None = None,
+                    use_kernel: bool | None = None,
                     return_visits: bool = False,
                     k_scale=None, v_scale=None):
-    """Decode: q (B, H, D); pools (P, bs, KH, D/DV) -> (B, H, DV).
+    """Decode: q (B, H, D); pools (P, KH, bs, D/DV) -> (B, H, DV).
 
-    ``k_scale``/``v_scale`` (P, bs, KH) mark the pools as quantized: the
-    kernel fuses dequantization into its load epilogue; the reference
-    dequantizes the gathered history.  Scale pools shard exactly like
-    their KV pools (kv_heads over the model axis) — same placement, same
-    shard_map specs minus the head_dim axis."""
-    if use_kernel and isinstance(window, int):
-        if interpret is None:
-            interpret = not _on_tpu()
+    ``k_scale``/``v_scale`` (P, KH, bs) mark the pools as quantized: the
+    kernel fuses dequantization into its score and value products; the
+    reference dequantizes the gathered history.  Scale pools shard
+    exactly like their KV pools (kv_heads over the model axis) — same
+    placement, same shard_map specs minus the head_dim axis."""
+    if use_kernel is None:
+        use_kernel = _kernel_path(window)
+    if use_kernel:
         fn = functools.partial(paged_attention_kernel, window=window,
-                               scale=scale, interpret=interpret,
+                               scale=scale, interpret=not _on_tpu(),
                                return_visits=return_visits)
-        part = _serve_partition(q.shape[0], q.shape[1], k_pool.shape[2])
+        part = _serve_partition(q.shape[0], q.shape[1], k_pool.shape[1])
         if part is not None:
             mesh, bd, hd = part
             bd, hd = (bd or None), (hd or None)
-            in_specs = (P(bd, hd, None), P(None, None, hd, None),
-                        P(None, None, hd, None), P(bd, None), P(bd))
+            in_specs = (P(bd, hd, None), P(None, hd, None, None),
+                        P(None, hd, None, None), P(bd, None), P(bd))
             if k_scale is not None:
-                in_specs += (P(None, None, hd), P(None, None, hd))
+                in_specs += (P(None, hd, None), P(None, hd, None))
                 fn = _positional_scales(fn)
-            fn = shard_map(
+            fn = jax.shard_map(
                 fn, mesh=mesh, in_specs=in_specs,
                 out_specs=(P(bd, hd, None), P(bd, hd)) if return_visits
                 else P(bd, hd, None),
-                check_rep=False)
+                check_vma=False)
             if k_scale is not None:
                 return fn(q, k_pool, v_pool, block_tables, kv_lens,
                           k_scale, v_scale)
@@ -131,32 +139,31 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
 def paged_prefill_attention(q, k_pool, v_pool, block_tables, q_starts,
                             kv_lens, *, window=0,
                             scale: float | None = None,
-                            use_kernel: bool = True,
-                            interpret: bool | None = None,
+                            use_kernel: bool | None = None,
                             return_visits: bool = False,
                             k_scale=None, v_scale=None):
     """Chunked prefill: q (B, C, H, D) -> (B, C, H, DV)."""
-    if use_kernel and isinstance(window, int):
-        if interpret is None:
-            interpret = not _on_tpu()
+    if use_kernel is None:
+        use_kernel = _kernel_path(window)
+    if use_kernel:
         fn = functools.partial(paged_prefill_attention_kernel, window=window,
-                               scale=scale, interpret=interpret,
+                               scale=scale, interpret=not _on_tpu(),
                                return_visits=return_visits)
-        part = _serve_partition(q.shape[0], q.shape[2], k_pool.shape[2])
+        part = _serve_partition(q.shape[0], q.shape[2], k_pool.shape[1])
         if part is not None:
             mesh, bd, hd = part
             bd, hd = (bd or None), (hd or None)
-            in_specs = (P(bd, None, hd, None), P(None, None, hd, None),
-                        P(None, None, hd, None), P(bd, None), P(bd),
+            in_specs = (P(bd, None, hd, None), P(None, hd, None, None),
+                        P(None, hd, None, None), P(bd, None), P(bd),
                         P(bd))
             if k_scale is not None:
-                in_specs += (P(None, None, hd), P(None, None, hd))
+                in_specs += (P(None, hd, None), P(None, hd, None))
                 fn = _positional_scales(fn)
-            fn = shard_map(
+            fn = jax.shard_map(
                 fn, mesh=mesh, in_specs=in_specs,
                 out_specs=(P(bd, None, hd, None), P(bd, hd))
                 if return_visits else P(bd, None, hd, None),
-                check_rep=False)
+                check_vma=False)
             if k_scale is not None:
                 return fn(q, k_pool, v_pool, block_tables, q_starts,
                           kv_lens, k_scale, v_scale)

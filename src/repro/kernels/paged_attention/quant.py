@@ -5,8 +5,8 @@ K/V history of every active slot through the paged-attention kernel, so
 bytes-per-token is the capacity *and* the latency knob.  A quantized pool
 stores K/V in int8 or fp8-e4m3 (1 byte/element) plus one f32 scale per
 written (token slot, kv-head) — the scale pools mirror the KV pools'
-block layout ``(num_blocks, block_size, KH)``, so a scale is addressed by
-exactly the same ``(block, offset, kv_head)`` coordinates as the vector
+block layout ``(num_blocks, KH, block_size)``, so a scale is addressed by
+exactly the same ``(block, kv_head, offset)`` coordinates as the vector
 it scales and travels with its block through prefix aliasing, COW copies
 and speculative rollback for free (DESIGN.md §11).
 
@@ -23,7 +23,7 @@ deterministic, history-free, and exactly reproducible by the jnp
 reference.
 
 Everything here is shared by ``models.attention._scatter_kv`` (the only
-writer), the Pallas kernel's fused load->dequant epilogue, and the
+writer), the Pallas kernel's fused dequant, and the
 reference oracle — so "what do the stored bytes mean" exists once.
 """
 from __future__ import annotations

@@ -6,12 +6,12 @@ per-sequence block table (vLLM-style paging).
 
 Shapes:
   q            (B, H, D)         one decode token per sequence, H = KH * G
-  k_pool       (P, bs, KH, D)    shared block pool (P blocks of bs tokens)
-  v_pool       (P, bs, KH, DV)
+  k_pool       (P, KH, bs, D)    shared block pool (P blocks of bs tokens,
+  v_pool       (P, KH, bs, DV)   head-major within a block)
   block_tables (B, NB) int32     pool index of each logical block
   kv_lens      (B,)    int32     valid tokens per sequence (incl. current)
   window       int | (B,) array  0 = full causal; >0 = sliding window
-  k/v_scale    (P, bs, KH) f32   per-write dequant scales when the pools
+  k/v_scale    (P, KH, bs) f32   per-write dequant scales when the pools
                                  are quantized (int8 / fp8-e4m3)
 
 Output (B, H, DV).  The reference materializes the gathered history
@@ -30,13 +30,14 @@ NEG_INF = -1e30
 def _gather_kv(pool, block_tables, scale):
     """Gather (B, S, KH, d) history from the pool, dequantizing with the
     identically-gathered scales when given — the same bytes->values rule
-    as the kernel's fused epilogue, applied after materialization."""
+    as the kernel's fused dequant, applied after materialization."""
     B, NB = block_tables.shape
-    bs = pool.shape[1]
-    out = pool[block_tables].reshape(B, NB * bs, pool.shape[2], -1)
+    KH, bs = pool.shape[1], pool.shape[2]
+    out = pool[block_tables].transpose(0, 1, 3, 2, 4) \
+        .reshape(B, NB * bs, KH, -1)
     if scale is not None:
-        out = dequantize(
-            out, scale[block_tables].reshape(B, NB * bs, pool.shape[2]))
+        out = dequantize(out, scale[block_tables].transpose(0, 1, 3, 2)
+                         .reshape(B, NB * bs, KH))
     return out
 
 
@@ -51,7 +52,7 @@ def paged_prefill_attention_reference(q, k_pool, v_pool, block_tables,
     sequence's valid count produce garbage the caller discards.  Output
     (B, C, H, DV)."""
     B, C, H, D = q.shape
-    bs, KH = k_pool.shape[1], k_pool.shape[2]
+    KH, bs = k_pool.shape[1], k_pool.shape[2]
     NB = block_tables.shape[1]
     G = H // KH
     scale = scale if scale is not None else D ** -0.5
@@ -81,7 +82,7 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables, kv_lens, *,
                               window=0, scale: float | None = None,
                               k_scale=None, v_scale=None) -> jax.Array:
     B, H, D = q.shape
-    bs, KH = k_pool.shape[1], k_pool.shape[2]
+    KH, bs = k_pool.shape[1], k_pool.shape[2]
     NB = block_tables.shape[1]
     G = H // KH
     scale = scale if scale is not None else D ** -0.5

@@ -1,4 +1,5 @@
-"""jit wrapper for the SSD scan kernel (ref on CPU, Pallas on TPU)."""
+"""jit wrapper for the SSD scan kernel (compiled on TPU, interpreted
+elsewhere)."""
 from __future__ import annotations
 
 import jax
@@ -11,12 +12,11 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def ssd_scan(x, dt, A, B, C, chunk: int, interpret: bool | None = None):
+def ssd_scan(x, dt, A, B, C, chunk: int):
     """Chunked SSD scan; same contract as models.ssm.ssd_reference minus the
-    final state (training path does not need it)."""
-    if interpret is None:
-        interpret = not _on_tpu()
-    return ssd_scan_pallas(x, dt, A, B, C, chunk, interpret=interpret)
+    final state (training path does not need it).  Compiled on the TPU,
+    interpreted elsewhere."""
+    return ssd_scan_pallas(x, dt, A, B, C, chunk, interpret=not _on_tpu())
 
 
 def ssd_scan_ref(x, dt, A, B, C, chunk: int):

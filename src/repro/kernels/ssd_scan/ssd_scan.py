@@ -36,18 +36,32 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *,
 
     x = x_ref[0, 0, 0].astype(jnp.float32)          # (Q, hp), dt pre-applied
     dt = dt_ref[0, 0, 0].astype(jnp.float32)        # (Q, 1)
-    A = a_ref[0, 0]                                  # scalar
+    A = a_ref[pl.program_id(1)]                      # scalar, from SMEM
     Bm = b_ref[0, 0].astype(jnp.float32)             # (Q, n)
     Cm = c_ref[0, 0].astype(jnp.float32)             # (Q, n)
 
-    dA = dt[:, 0] * A                                # (Q,)
-    cs = jnp.cumsum(dA)                              # (Q,)
-    # segsum decay kernel L[i, j] = exp(cs_i - cs_j + dA_j') lower-tri:
-    # exact form: sum_{j<k<=i} dA_k = cs_i - cs_j
-    diff = cs[:, None] - cs[None, :]
+    dA = dt * A                                      # (Q, 1)
+    # prefix sums as products with triangular ones matrices (the TPU
+    # lowers no cumsum), in both orientations for the pairwise segsum
+    # sum_{j<k<=i} dA_k = cs_i - cs_j, plus the suffix sums that decay
+    # each position's state contribution to the chunk's end
     ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    L = jnp.where(ii >= jj, jnp.exp(diff), 0.0)      # (Q, Q)
+    tril = (ii >= jj).astype(jnp.float32)
+
+    def mm(a, b, dims):
+        return jax.lax.dot_general(a, b, (dims, ((), ())),
+                                   precision=jax.lax.Precision.HIGHEST,
+                                   preferred_element_type=jnp.float32)
+
+    cs = mm(tril, dA, ((1,), (0,)))                  # (Q, 1)
+    cs_row = mm(dA, tril, ((0,), (1,)))              # (1, Q)
+    L = jnp.where(ii >= jj, jnp.exp(cs - cs_row), 0.0)   # (Q, Q)
+    decay_states = jnp.exp(mm((jj > ii).astype(jnp.float32), dA,
+                              ((1,), (0,))))         # (Q, 1) exp(cs_Q - cs)
+    p = x.shape[1]
+    decay_chunk = jnp.exp(mm(jnp.ones((p, chunk), jnp.float32), dA,
+                             ((1,), (0,))))          # (hp, 1) exp(cs_Q)
 
     G = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)   # (Q, Q)
@@ -55,15 +69,14 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *,
                                  preferred_element_type=jnp.float32)
 
     state = state_ref[...]                           # (hp, n)
-    y_off = jnp.exp(cs)[:, None] * jax.lax.dot_general(
+    y_off = jnp.exp(cs) * jax.lax.dot_general(
         Cm, state, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)          # (Q, hp)
 
-    decay_states = jnp.exp(cs[-1] - cs)              # (Q,)
-    upd = jax.lax.dot_general(x, Bm * decay_states[:, None],
+    upd = jax.lax.dot_general(x, Bm * decay_states,
                               (((0,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)  # (hp, n)
-    state_ref[...] = state * jnp.exp(cs[-1]) + upd
+    state_ref[...] = state * decay_chunk + upd
 
     y_ref[0, 0, 0] = (y_diag + y_off).astype(y_ref.dtype)
 
@@ -81,7 +94,6 @@ def ssd_scan_pallas(x, dt, A, B, C, chunk: int, interpret: bool = True):
     dtk = dt.transpose(0, 2, 1).reshape(b, h, nc, chunk, 1)
     bk = B.reshape(b, nc, chunk, n)
     ck = C.reshape(b, nc, chunk, n)
-    ak = jnp.broadcast_to(A.astype(jnp.float32)[None, :], (b, h))
 
     kernel = functools.partial(_kernel, chunk=chunk)
     y = pl.pallas_call(
@@ -90,7 +102,7 @@ def ssd_scan_pallas(x, dt, A, B, C, chunk: int, interpret: bool = True):
         in_specs=[
             pl.BlockSpec((1, 1, 1, chunk, p), lambda i, j, c: (i, j, c, 0, 0)),
             pl.BlockSpec((1, 1, 1, chunk, 1), lambda i, j, c: (i, j, c, 0, 0)),
-            pl.BlockSpec((1, 1), lambda i, j, c: (i, j)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),       # A (h,) whole
             pl.BlockSpec((1, 1, chunk, n), lambda i, j, c: (i, c, 0, 0)),
             pl.BlockSpec((1, 1, chunk, n), lambda i, j, c: (i, c, 0, 0)),
         ],
@@ -99,5 +111,6 @@ def ssd_scan_pallas(x, dt, A, B, C, chunk: int, interpret: bool = True):
         out_shape=jax.ShapeDtypeStruct((b, h, nc, chunk, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
-    )(xk, dtk, ak, bk, ck)
+        name="ssd_scan",
+    )(xk, dtk, A.astype(jnp.float32), bk, ck)
     return y.reshape(b, h, l, p).transpose(0, 2, 1, 3)

@@ -1,7 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import (jax locks the device
-# count at first init).  Do not move them.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# The lines above MUST run before any other import (jax locks the device
+# count at first init).  Do not move them.  CPU-only tool: it compiles
+# for 512 virtual host devices and must never claim an accelerator.
 
 """Multi-pod dry-run: lower + compile every (arch x shape) on the production
 meshes and record memory / cost / collective analysis for the roofline.

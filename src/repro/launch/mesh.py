@@ -7,17 +7,13 @@ import; smoke tests see one CPU device).
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from repro.configs.base import ArchConfig, ShapeConfig
 from repro.distributed.sharding import ShardingRules
 
 
 def _mk(shape, axes) -> Mesh:
-    try:
-        from jax.sharding import AxisType
-    except ImportError:      # JAX < 0.5: all mesh axes are Auto already
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

@@ -182,7 +182,9 @@ def _serve_replicated(engines, args, toks, lens, stop, telemetry):
               f"(one phase track per replica)")
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
+    """The serving CLI's flags; ``build_engine`` reads the parsed
+    namespace (chip_smoke.py builds its engines through both)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -268,7 +270,13 @@ def main():
                     help="restore engine state from a snapshot file and "
                          "resume its waiting queue (engine flags come "
                          "from the snapshot, not the CLI)")
-    args = ap.parse_args()
+    return ap
+
+
+def main():
+    args = build_parser().parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

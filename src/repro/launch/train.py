@@ -16,6 +16,7 @@ import json
 
 from repro.configs import get_config, reduced as reduce_cfg
 from repro.data.synthetic import batches
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build
 from repro.train.loop import Trainer, TrainerConfig, run_with_restarts
 from repro.train.optim import OptConfig
@@ -40,6 +41,7 @@ def main():
                     help="prune after this many steps, then keep training")
     ap.add_argument("--out", default="")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

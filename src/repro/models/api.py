@@ -156,12 +156,12 @@ class Model:
         spec: dict[str, Any] = {}
         if cfg.family != "ssm":
             KH = cfg.n_kv_heads
-            spec["k"] = sds((L, num_blocks, block_size, KH, cfg.head_dim_), dt)
-            spec["v"] = sds((L, num_blocks, block_size, KH, cfg.v_head_dim_),
+            spec["k"] = sds((L, num_blocks, KH, block_size, cfg.head_dim_), dt)
+            spec["v"] = sds((L, num_blocks, KH, block_size, cfg.v_head_dim_),
                             dt)
             if quant:
                 for name in ("k_scale", "v_scale"):
-                    spec[name] = sds((L, num_blocks, block_size, KH),
+                    spec[name] = sds((L, num_blocks, KH, block_size),
                                      jnp.float32)
         if cfg.family == "ssm" or cfg.hybrid:
             nh, hp, n = cfg.ssm_n_heads, cfg.ssm_head_dim, cfg.ssm_state

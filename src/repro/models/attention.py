@@ -165,7 +165,7 @@ def _scatter_kv(kv: dict, k_new, v_new, block_tables, positions,
     absolute ``positions`` (B, C) map to through ``block_tables`` (B, NB).
 
     ``kv`` is one layer's pool slice: ``{"k", "v"}`` plus, when the pool
-    is quantized, ``{"k_scale", "v_scale"}`` (P, bs, KH) f32.  ``inchunk``
+    is quantized, ``{"k_scale", "v_scale"}`` (P, KH, bs) f32.  ``inchunk``
     (B, C) bool masks padding: masked tokens (and positions pointing past
     the table) are redirected to the reserved null block 0, where writes
     are harmless by construction.  Shared by the paged decode,
@@ -177,23 +177,25 @@ def _scatter_kv(kv: dict, k_new, v_new, block_tables, positions,
     symmetrically on write, storing the per-(token, kv-head) scale at the
     same (block, offset) coordinates (DESIGN.md §11)."""
     k_pool, v_pool = kv["k"], kv["v"]
-    bs, NB = k_pool.shape[1], block_tables.shape[1]
+    KH, bs, NB = k_pool.shape[1], k_pool.shape[2], block_tables.shape[1]
     blk_idx = jnp.clip(positions // bs, 0, NB - 1)
     blk = jnp.take_along_axis(block_tables, blk_idx, axis=1)
     off = positions % bs
     if inchunk is not None:
         blk = jnp.where(inchunk, blk, 0)
         off = jnp.where(inchunk, off, 0)
+    # pools are head-major within a block: (block, head, offset) per token
+    at = (blk[..., None], jnp.arange(KH), off[..., None])
     if "k_scale" in kv:
         from repro.kernels.paged_attention import quantize
         qk, sk = quantize(k_new, k_pool.dtype)
         qv, sv = quantize(v_new, v_pool.dtype)
-        return {"k": k_pool.at[blk, off].set(qk),
-                "v": v_pool.at[blk, off].set(qv),
-                "k_scale": kv["k_scale"].at[blk, off].set(sk),
-                "v_scale": kv["v_scale"].at[blk, off].set(sv)}
-    return {"k": k_pool.at[blk, off].set(k_new.astype(k_pool.dtype)),
-            "v": v_pool.at[blk, off].set(v_new.astype(v_pool.dtype))}
+        return {"k": k_pool.at[at].set(qk),
+                "v": v_pool.at[at].set(qv),
+                "k_scale": kv["k_scale"].at[at].set(sk),
+                "v_scale": kv["v_scale"].at[at].set(sv)}
+    return {"k": k_pool.at[at].set(k_new.astype(k_pool.dtype)),
+            "v": v_pool.at[at].set(v_new.astype(v_pool.dtype))}
 
 
 def attention_paged_decode(params: dict, cfg, x: jax.Array,
@@ -205,10 +207,10 @@ def attention_paged_decode(params: dict, cfg, x: jax.Array,
     x (B,1,d); positions (B,) int32 — per-sequence write index (sequences in
     a serving batch are at *different* depths, unlike ``attention_decode``'s
     single scalar pos).  ``kv`` is one layer's pool slice ``{"k", "v"}``
-    (P, bs, KH, hd/vhd), plus ``{"k_scale", "v_scale"}`` when quantized;
+    (P, KH, bs, hd/vhd), plus ``{"k_scale", "v_scale"}`` when quantized;
     block_tables (B, NB) maps logical to pool blocks.  window: python int
-    for static masking (Pallas-able) or a (B,) array for per-sequence
-    dynamic windows (hybrid layers; reference path).
+    for static masking (the kernel on TPU) or a (B,) array for
+    per-sequence dynamic windows (hybrid layers; reference path).
 
     Returns (out (B,1,d), new kv dict).
     """
@@ -219,8 +221,7 @@ def attention_paged_decode(params: dict, cfg, x: jax.Array,
     kv = _scatter_kv(kv, k_new, v_new, block_tables, positions[:, None])
     qf = q.reshape(B, q.shape[2] * q.shape[3], q.shape[4])
     o = paged_attention(qf, kv["k"], kv["v"], block_tables, positions + 1,
-                        window=window, use_kernel=cfg.use_pallas,
-                        k_scale=kv.get("k_scale"),
+                        window=window, k_scale=kv.get("k_scale"),
                         v_scale=kv.get("v_scale"))
     o = o[:, None]                                       # (B, 1, H, vhd)
     out = jnp.einsum("bshk,hkd->bsd", o, params["wo"])
@@ -255,7 +256,7 @@ def attention_paged_prefill(params: dict, cfg, x: jax.Array,
     qf = q.reshape(B, C, q.shape[2] * q.shape[3], q.shape[4])
     o = paged_prefill_attention(
         qf, kv["k"], kv["v"], block_tables, positions[:, 0],
-        positions[:, 0] + valid, window=window, use_kernel=cfg.use_pallas,
+        positions[:, 0] + valid, window=window,
         k_scale=kv.get("k_scale"), v_scale=kv.get("v_scale"))
     out = jnp.einsum("bshk,hkd->bsd", o, params["wo"])
     return out, kv

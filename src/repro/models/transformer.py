@@ -322,11 +322,11 @@ def paged_cache_axes(cfg: ArchConfig, quantized: bool = False) -> dict:
     per-device replicas carry scales alongside (DESIGN.md §10/§11)."""
     axes: dict[str, Any] = {}
     if cfg.family != "ssm":
-        axes["k"] = ("layers", "serve_blocks", None, "kv_heads", None)
-        axes["v"] = ("layers", "serve_blocks", None, "kv_heads", None)
+        axes["k"] = ("layers", "serve_blocks", "kv_heads", None, None)
+        axes["v"] = ("layers", "serve_blocks", "kv_heads", None, None)
         if quantized:
-            axes["k_scale"] = ("layers", "serve_blocks", None, "kv_heads")
-            axes["v_scale"] = ("layers", "serve_blocks", None, "kv_heads")
+            axes["k_scale"] = ("layers", "serve_blocks", "kv_heads", None)
+            axes["v_scale"] = ("layers", "serve_blocks", "kv_heads", None)
     if cfg.family == "ssm" or cfg.hybrid:
         axes["conv"] = ("layers", "serve_batch", None, None)
         axes["state"] = ("layers", "serve_batch", "ssm_heads", None, None)
@@ -448,7 +448,7 @@ def init_paged_cache(cfg: ArchConfig, num_blocks: int, block_size: int,
     never correctness, DESIGN.md §9); a quantized dtype ("int8",
     "fp8_e4m3") additionally allocates per-(block, token, kv-head) f32
     scale pools mirroring the KV pools' block layout, written by
-    ``_scatter_kv`` and consumed by the kernel's fused dequant epilogue
+    ``_scatter_kv`` and consumed by the kernel's fused dequant
     (DESIGN.md §11).
     """
     from repro.kernels.paged_attention import is_quantized, pool_dtype
@@ -458,11 +458,13 @@ def init_paged_cache(cfg: ArchConfig, num_blocks: int, block_size: int,
     cache: dict[str, Any] = {}
     if cfg.family != "ssm":
         KH, hd, vhd = cfg.n_kv_heads, cfg.head_dim_, cfg.v_head_dim_
-        cache["k"] = jnp.zeros((L, num_blocks, block_size, KH, hd), dt)
-        cache["v"] = jnp.zeros((L, num_blocks, block_size, KH, vhd), dt)
+        # head-major within a block: one block of one head is a
+        # contiguous (block_size, hd) tile for the paged-attention kernel
+        cache["k"] = jnp.zeros((L, num_blocks, KH, block_size, hd), dt)
+        cache["v"] = jnp.zeros((L, num_blocks, KH, block_size, vhd), dt)
         if quant:
             for name in ("k_scale", "v_scale"):
-                cache[name] = jnp.zeros((L, num_blocks, block_size, KH),
+                cache[name] = jnp.zeros((L, num_blocks, KH, block_size),
                                         jnp.float32)
     if cfg.family == "ssm" or cfg.hybrid:
         # recurrent state keeps the compute dtype: it is carried, not

@@ -44,8 +44,8 @@ copy-on-write (src, dst) pool copies which the engine runs on device
 Quantized KV pools (``cache_dtype="int8"``/``"fp8_e4m3"``, DESIGN.md
 §11): the pools store 1-byte elements plus per-(token, kv-head) f32
 scale pools that share the KV pools' block addressing — ``_scatter_kv``
-quantizes on write, the paged-attention kernel dequantizes in its load
-epilogue, and the engine's only added duty is COWing the scale pools
+quantizes on write, the paged-attention kernel fuses the dequant into
+its score and value products, and the engine's only added duty is COWing the scale pools
 alongside k/v.  Host bookkeeping is unchanged, so scheduler behavior is
 byte-identical across cache dtypes; ~3.8x more history fits per HBM
 byte vs f32 (benchmarks/serving.py --cache-dtype).
@@ -91,7 +91,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.distributed.sharding import tree_shardings, use_rules
@@ -460,7 +459,7 @@ class Engine:
 
     def _make_fn(self, impl, which: str, donate: tuple[int, ...]):
         """Jit one device step.  "dp" mode wraps the impl in shard_map
-        first: per-device pool replicas (specs P() with check_rep=False —
+        first: per-device pool replicas (specs P() with check_vma=False —
         replicas legitimately diverge on foreign slots' blocks) and every
         slot-batched operand — decode rows AND prefill chunks — split
         over `data`, so each shard computes and writes only its own
@@ -479,8 +478,8 @@ class Engine:
                         key, jax.lax.axis_index("data"))
                     return _inner(*rest, key)
             in_specs, out_specs = self._dp_specs(which)
-            impl = shard_map(impl, mesh=self.mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False)
+            impl = jax.shard_map(impl, mesh=self.mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False)
         return jax.jit(impl,
                        donate_argnums=donate if self._donate_pools else (),
                        **self._jit_shardings(which))

@@ -138,14 +138,14 @@ def test_paged_attention_kernel_parity(B, H, KH, D, DV, bs, NB, window,
                                        dtype):
     P = B * NB + 1
     q = jnp.asarray(rng.normal(size=(B, H, D)), dtype)
-    kp = jnp.asarray(rng.normal(size=(P, bs, KH, D)), dtype)
-    vp = jnp.asarray(rng.normal(size=(P, bs, KH, DV)), dtype)
+    kp = jnp.asarray(rng.normal(size=(P, KH, bs, D)), dtype)
+    vp = jnp.asarray(rng.normal(size=(P, KH, bs, DV)), dtype)
     # non-contiguous tables: shuffled pool blocks, none uses block 0
     tables = jnp.asarray(
         1 + rng.permutation(B * NB).reshape(B, NB), jnp.int32)
     lens = jnp.asarray(rng.integers(1, NB * bs + 1, size=(B,)), jnp.int32)
     out = paged_attention(q, kp, vp, tables, lens, window=window,
-                          use_kernel=True, interpret=True)
+                          use_kernel=True)
     ref = paged_attention_reference(q, kp, vp, tables, lens, window=window)
     tol = 3e-2 if dtype == jnp.bfloat16 else 1e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -160,13 +160,12 @@ def test_paged_attention_skips_fully_masked_blocks():
     B, H, KH, D, bs, NB = 3, 4, 2, 16, 4, 16          # 64-token tables
     P = B * NB + 1
     q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(P, bs, KH, D)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, bs, KH, D)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(P, KH, bs, D)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(P, KH, bs, D)), jnp.float32)
     tables = jnp.asarray(1 + rng.permutation(B * NB).reshape(B, NB),
                          jnp.int32)
     lens = jnp.asarray([1, 5, 9], jnp.int32)          # 1-3 of 16 blocks live
-    out, visits = paged_attention(q, kp, vp, tables, lens, use_kernel=True,
-                                  interpret=True, return_visits=True)
+    out, visits = paged_attention(q, kp, vp, tables, lens, use_kernel=True, return_visits=True)
     ref = paged_attention_reference(q, kp, vp, tables, lens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
@@ -182,13 +181,12 @@ def test_paged_attention_window_skips_left_of_window():
     B, H, KH, D, bs, NB = 1, 2, 2, 16, 4, 8
     P = B * NB + 1
     q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(P, bs, KH, D)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, bs, KH, D)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(P, KH, bs, D)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(P, KH, bs, D)), jnp.float32)
     tables = jnp.asarray(1 + rng.permutation(NB)[None], jnp.int32)
     lens = jnp.asarray([NB * bs], jnp.int32)           # full table...
     out, visits = paged_attention(q, kp, vp, tables, lens, window=6,
-                                  use_kernel=True, interpret=True,
-                                  return_visits=True)
+                                  use_kernel=True, return_visits=True)
     ref = paged_attention_reference(q, kp, vp, tables, lens, window=6)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
@@ -207,8 +205,8 @@ def test_paged_prefill_kernel_parity(B, C, H, KH, D, bs, NB):
         paged_prefill_attention, paged_prefill_attention_reference)
     P = B * NB + 1
     q = jnp.asarray(rng.normal(size=(B, C, H, D)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(P, bs, KH, D)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, bs, KH, D)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(P, KH, bs, D)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(P, KH, bs, D)), jnp.float32)
     tables = jnp.asarray(1 + rng.permutation(B * NB).reshape(B, NB),
                          jnp.int32)
     starts = jnp.asarray(rng.integers(0, NB * bs - C + 1, size=(B,)),
@@ -216,7 +214,7 @@ def test_paged_prefill_kernel_parity(B, C, H, KH, D, bs, NB):
     valid = rng.integers(1, C + 1, size=(B,))
     lens = starts + jnp.asarray(valid, jnp.int32)
     out = paged_prefill_attention(q, kp, vp, tables, starts, lens,
-                                  use_kernel=True, interpret=True)
+                                  use_kernel=True)
     ref = paged_prefill_attention_reference(q, kp, vp, tables, starts, lens)
     for b in range(B):                 # rows past valid are don't-care
         np.testing.assert_allclose(np.asarray(out)[b, :valid[b]],
@@ -237,8 +235,8 @@ def test_paged_attention_matches_contiguous_flash():
         jnp.concatenate([jnp.zeros((B, S - 1, H, D), jnp.float32), q1], 1),
         k, v, causal=True)[:, -1]
     # pools: per-sequence contiguous layout packed into one pool
-    kp = k.reshape(B * NB, bs, KH, D)
-    vp = v.reshape(B * NB, bs, KH, D)
+    kp = k.reshape(B * NB, bs, KH, D).transpose(0, 2, 1, 3)
+    vp = v.reshape(B * NB, bs, KH, D).transpose(0, 2, 1, 3)
     tables = jnp.arange(B * NB, dtype=jnp.int32).reshape(B, NB)
     lens = jnp.full((B,), S, jnp.int32)
     out = paged_attention(q1[:, 0], kp, vp, tables, lens, use_kernel=False)

@@ -41,8 +41,8 @@ QTOL = {"int8": 2e-2, "fp8_e4m3": 1e-1}
 
 
 def _quantized_pools(P, bs, KH, D, DV, dtype_name):
-    k = jnp.asarray(rng.normal(size=(P, bs, KH, D)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(P, bs, KH, DV)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(P, KH, bs, D)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(P, KH, bs, DV)), jnp.float32)
     dt = pool_dtype(dtype_name)
     qk, sk = quantize(k, dt)
     qv, sv = quantize(v, dt)
@@ -70,8 +70,7 @@ def test_quantized_decode_kernel_parity(dtype_name, B, H, KH, D, DV, bs,
     lens = jnp.asarray(rng.integers(1, NB * bs + 1, size=(B,)), jnp.int32)
 
     out = paged_attention(q, qk, qv, tables, lens, window=window,
-                          use_kernel=True, interpret=True,
-                          k_scale=sk, v_scale=sv)
+                          use_kernel=True, k_scale=sk, v_scale=sv)
     ref = paged_attention_reference(q, qk, qv, tables, lens, window=window,
                                     k_scale=sk, v_scale=sv)
     # fused dequant == gather-then-dequant: same bytes, same values
@@ -101,8 +100,7 @@ def test_quantized_prefill_kernel_parity(dtype_name, B, C, H, KH, D, bs,
     lens = starts + jnp.asarray(valid, jnp.int32)
 
     out = paged_prefill_attention(q, qk, qv, tables, starts, lens,
-                                  use_kernel=True, interpret=True,
-                                  k_scale=sk, v_scale=sv)
+                                  use_kernel=True, k_scale=sk, v_scale=sv)
     ref = paged_prefill_attention_reference(q, qk, qv, tables, starts, lens,
                                             k_scale=sk, v_scale=sv)
     full = paged_prefill_attention_reference(q, k, v, tables, starts, lens)
@@ -129,8 +127,7 @@ def test_quantized_window_skip_visit_counters():
     for window, lens in ((6, [32, 13]), (3, [9, 27]), (12, [32, 5])):
         lens_a = jnp.asarray(lens, jnp.int32)
         out, visits = paged_attention(q, qk, qv, tables, lens_a,
-                                      window=window, use_kernel=True,
-                                      interpret=True, return_visits=True,
+                                      window=window, use_kernel=True, return_visits=True,
                                       k_scale=sk, v_scale=sv)
         ref = paged_attention_reference(q, qk, qv, tables, lens_a,
                                         window=window, k_scale=sk,
@@ -218,7 +215,7 @@ def test_engine_scale_pools_allocated(key):
     assert eng.cache["v"].dtype == jnp.int8
     for name in ("k_scale", "v_scale"):
         assert eng.cache[name].dtype == jnp.float32
-        # scales mirror the pools' (L, P, bs, KH) block layout
+        # scales mirror the pools' (L, P, KH, bs) block layout
         assert eng.cache[name].shape == eng.cache["k"].shape[:-1]
 
 

@@ -180,13 +180,16 @@ def test_sharded_quantized_cache_matches_one_device(key):
         eng.cache_host.check()
 
 
-def test_sharded_pallas_kernel_matches_one_device(key):
-    """use_pallas engines route paged attention through the kernel; under
-    a sharded mesh the kernel call is shard_map'd per device (gspmd mode)
-    and must stay byte-identical."""
-    m, params = _models(key, False)
-    mk = build(m.cfg.replace(use_pallas=True))
-    prompts = _prompts(m.cfg, n=4)
+def test_sharded_pallas_kernel_matches_one_device(key, monkeypatch):
+    """Engines whose paged attention runs the kernel (the TPU choice,
+    forced here on the CPU in interpret mode); under a sharded mesh the
+    kernel call is shard_map'd per device (gspmd mode) and must stay
+    byte-identical."""
+    from repro.kernels.paged_attention import ops
+    monkeypatch.setattr(ops, "_kernel_path",
+                        lambda window: isinstance(window, int))
+    mk, params = _models(key, False)
+    prompts = _prompts(mk.cfg, n=4)
     sc = ServeConfig(max_seqs=4, block_size=4, max_len=32)
     ref, _ = _serve(Engine(mk, params, sc), prompts)
     for dm in _mesh_shapes():
@@ -207,28 +210,29 @@ def test_kernel_shard_map_wrap_matches_unsharded():
     rng = np.random.default_rng(0)
     B, H, KH, D, bs, NB = 4, 4, 2, 8, 4, 3
     P = B * NB + 1
-    kp = jnp.asarray(rng.normal(size=(P, bs, KH, D)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, bs, KH, D)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(P, KH, bs, D)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(P, KH, bs, D)), jnp.float32)
     tables = jnp.asarray(
         1 + np.arange(B * NB, dtype=np.int32).reshape(B, NB))
     lens = jnp.asarray([5, 9, 12, 7], jnp.int32)
     q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
-    ref = paged_attention(q, kp, vp, tables, lens)
+    ref = paged_attention(q, kp, vp, tables, lens, use_kernel=True)
 
     mesh = make_serve_mesh(len(jax.devices()), 1)
     rules = serve_rules(get_config("tinyllama-1.1b").replace(
         n_kv_heads=KH, n_heads=H), mesh)
     with use_rules(rules, mesh=mesh):
-        out = paged_attention(q, kp, vp, tables, lens)
+        out = paged_attention(q, kp, vp, tables, lens, use_kernel=True)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
     C = 4
     qc = jnp.asarray(rng.normal(size=(B, C, H, D)), jnp.float32)
     starts = jnp.asarray([2, 4, 0, 3], jnp.int32)
-    refc = paged_prefill_attention(qc, kp, vp, tables, starts, starts + C)
+    refc = paged_prefill_attention(qc, kp, vp, tables, starts, starts + C,
+                                   use_kernel=True)
     with use_rules(rules, mesh=mesh):
         outc = paged_prefill_attention(qc, kp, vp, tables, starts,
-                                       starts + C)
+                                       starts + C, use_kernel=True)
     np.testing.assert_array_equal(np.asarray(outc), np.asarray(refc))
 
 
