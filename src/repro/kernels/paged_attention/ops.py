@@ -13,6 +13,12 @@ counter (the fully-masked-block skip's observable); it is kernel-only —
 the reference materializes every table entry by construction, so asking
 it for visit counts is a bug.
 
+``grid_steps`` counts, on the host, the grid a call launches and the
+steps of it that touch a live block, from the lengths the call hands the
+kernel and with the kernel's own liveness predicate: the one place the
+grid's size is counted outside the kernel (the serving engine's
+``serve/*_attn_*_steps`` counters).
+
 Sharded serving (DESIGN.md §10): when the serving engine traces with an
 active mesh (``distributed.sharding.use_rules(rules, mesh=mesh)``), the
 kernel call wraps itself in ``shard_map`` — sequences split over the
@@ -32,11 +38,12 @@ import functools
 import math
 
 import jax
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import active_mesh, active_rules
 from repro.kernels.paged_attention.paged_attention import (
-    paged_attention_kernel, paged_prefill_attention_kernel)
+    _block_live, paged_attention_kernel, paged_prefill_attention_kernel)
 from repro.kernels.paged_attention.ref import (
     paged_attention_reference, paged_prefill_attention_reference)
 
@@ -49,6 +56,21 @@ def _kernel_path(window) -> bool:
     """Whether the compiled kernel serves this call: on the TPU, for a
     static window.  Tests that must steer the choice patch this."""
     return _on_tpu() and isinstance(window, int)
+
+
+def grid_steps(kv_lens, q_starts, num_table_blocks: int, block_size: int,
+               window: int = 0) -> tuple[int, int]:
+    """(live, launched) grid steps of one kernel call over a batch with
+    per-row ``kv_lens`` and ``q_starts`` (numpy, inactive rows included as
+    the kernel sees them) and a ``num_table_blocks``-wide block table.
+    The grid is (B, num_table_blocks); a step is live when
+    ``_block_live`` holds for it, and the kernel then DMAs and computes
+    its block (the per-row count is the kernel's ``visits``)."""
+    kv = np.asarray(kv_lens, np.int32)[:, None]
+    live = _block_live(np.arange(num_table_blocks, dtype=np.int32), kv,
+                       np.asarray(q_starts, np.int32)[:, None],
+                       window=window, block_size=block_size)
+    return int(np.count_nonzero(live)), kv.shape[0] * num_table_blocks
 
 
 def _positional_scales(fn):

@@ -419,12 +419,10 @@ def main():
         from repro.obs import prometheus_text
         reg = telemetry.registry
         print("\n-- step phases (per-step wall, us) --")
-        for name in ("step", "plan", "overlap", "prefill_dispatch",
-                     "decode_dispatch", "sync", "fold"):
-            h = reg.histograms.get("phase/" + name)
-            if h is None:
+        for key, h in sorted(reg.histograms.items()):
+            if not key.startswith("phase/"):
                 continue
-            s = h.summary()
+            name, s = key[len("phase/"):], h.summary()
             print(f"{name:18s} p50 {s['p50'] * 1e6:9.1f}  "
                   f"p99 {s['p99'] * 1e6:9.1f}  "
                   f"mean {s['mean'] * 1e6:9.1f}  n={s['count']}")

@@ -56,10 +56,14 @@ single ``jax.device_get`` (``stats["host_syncs"]``; asserted in
 tests/test_serve_spec.py).
 
 Observability (``Engine(..., telemetry=...)``; DESIGN.md §12): a
-``repro.obs.Telemetry`` handle records per-step phase timers (plan /
-prefill dispatch / decode-or-spec dispatch / the one device_get sync /
-host fold), per-request lifecycle spans (submit → admit → first chunk →
-first token → preempt/resume → finish) and per-step pool gauges.  All
+``repro.obs.Telemetry`` handle records per-step phase timers that tile
+the step (admin / plan / cow / prefill dispatch / decode-or-spec
+dispatch / the one device_get sync / host fold, with the consumer's
+``on_token`` callbacks as ``emit`` inside the fold, and the step's
+compile seconds as ``compile``; every phase but ``compile`` is also a
+``serve.<phase>`` profiler annotation), per-request lifecycle spans
+(submit → admit → first chunk → first token → preempt/resume → finish)
+and per-step pool gauges.  All
 instrumentation is host-side wall clock around the existing calls —
 never inside jitted code, never touching the RNG — so metrics-on and
 metrics-off engine outputs are byte-identical (tests/test_obs.py), and
@@ -95,7 +99,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.distributed.sharding import tree_shardings, use_rules
 from repro.kernels.paged_attention import CACHE_DTYPES, is_quantized
-from repro.obs import DEFAULT_TIME_BUCKETS, NULL_CTX, Telemetry
+from repro.kernels.paged_attention.ops import grid_steps
+from repro.obs import DEFAULT_TIME_BUCKETS, NULL_CTX, Telemetry, compile_log
 from repro.serve.faults import CrashError, FaultError, FaultInjector
 from repro.serve.kv_cache import OutOfBlocks, PagedCache
 from repro.serve.scheduler import FCFSScheduler, Request, RequestState
@@ -114,7 +119,16 @@ _RUN_COUNTERS = ("steps", "decode_tokens", "prefill_tokens",
                  # intra-mesh cross-shard aliasing (DESIGN.md §16):
                  # refused cross-shard prefix matches vs replica copies
                  # executed to make the alias legal
-                 "alias_refusals", "shard_moves")
+                 "alias_refusals", "shard_moves",
+                 # what the step programs carry (counted at dispatch from
+                 # the host arrays): real prefill tokens against the
+                 # call's token slots, and the paged-attention kernel's
+                 # live against launched grid steps (ops.grid_steps)
+                 "prefill_chunk_tokens", "prefill_token_slots",
+                 "decode_attn_live_steps", "decode_attn_grid_steps",
+                 "prefill_attn_live_steps", "prefill_attn_grid_steps",
+                 # programs traced while the engine stepped (obs.CompileLog)
+                 "compiles")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -347,6 +361,7 @@ class Engine:
         # equally-cheap attribute increments and back run()'s stats.
         self.obs = telemetry if telemetry is not None else \
             Telemetry(enabled=False)
+        self._compile_log = compile_log()
         # --- mesh-aware serving (DESIGN.md §10) ---------------------------
         # With a (data, model) mesh the engine becomes one sharded SPMD
         # program: block pools + head-sharded params go tensor-parallel
@@ -423,6 +438,10 @@ class Engine:
         self._prefix_ok = (self.cfg.prefix_caching
                            and model.cfg.family != "ssm"
                            and not model.cfg.hybrid)
+        # the paged-attention kernel serves attention with a static window
+        # (0 in the paged steps); hybrid layers' dynamic window takes the
+        # reference, and ssm has no attention: no grid to count
+        self._attn_grid = model.cfg.family != "ssm" and not model.cfg.hybrid
         # speculative decoding capability gate: rejected drafts roll back
         # by dropping KV cursor positions; recurrent SSM/conv state has no
         # such rewind, so SSM/hybrid fall back to dense-only decode
@@ -927,12 +946,14 @@ class Engine:
         if done:
             del self._on_token[rid]
         try:
-            if self.faults is not None and \
-                    self.faults.fire("callback_error", self._steps,
-                                     rid=rid) is not None:
-                self._c["faults_injected"].inc()
-                raise FaultError(f"injected on_token exception (rid {rid})")
-            cb(tok, done)
+            with self._phase("emit"):
+                if self.faults is not None and \
+                        self.faults.fire("callback_error", self._steps,
+                                         rid=rid) is not None:
+                    self._c["faults_injected"].inc()
+                    raise FaultError(
+                        f"injected on_token exception (rid {rid})")
+                cb(tok, done)
         except Exception:
             self._c["callback_errors"].inc()
             self._on_token.pop(rid, None)
@@ -958,6 +979,34 @@ class Engine:
         if not self.obs.enabled:
             return NULL_CTX
         return self.obs.phase(name, self._steps)
+
+    def _compile_mark(self) -> tuple[int, int, float]:
+        """(step, programs traced, compile seconds) as a step starts."""
+        return (self._steps, *self._compile_log.totals())
+
+    def _note_compiles(self, mark: tuple[int, int, float]) -> None:
+        """Count the programs traced since ``mark``; with telemetry on,
+        record their seconds as a ``compile`` phase of that step, ending
+        now."""
+        step, programs0, seconds0 = mark
+        programs, seconds = self._compile_log.totals()
+        if programs > programs0:
+            self._c["compiles"].inc(programs - programs0)
+        if seconds > seconds0:
+            self.obs.record_phase("compile", seconds - seconds0, step)
+
+    def _admin(self) -> None:
+        """The step's bookkeeping before planning: fault hooks, request
+        deadlines, the degradation ladder and the invariant audit."""
+        with self._phase("admin"):
+            self._fault_tick()
+            self._expire_deadlines()
+            self._degrade_tick()
+            # audit BEFORE dispatch: corruption is caught before the
+            # next step's plan/kernels consume it, so recovery can
+            # still rebuild without a corrupt-table step having
+            # committed wrong tokens (DESIGN.md §14)
+            self._audit_maybe()
 
     def _note_transitions(self, plan) -> None:
         """Queue-transition bookkeeping for this scheduling round:
@@ -997,16 +1046,6 @@ class Engine:
             self.obs.sample("prefix", {
                 "lookups": c.prefix_lookups, "hits": c.prefix_hits,
                 "hit_rate": c.prefix_hits / max(c.prefix_lookups, 1)})
-        # host bubble fraction: the share of step wall spent blocked in
-        # the device_get — the async pipeline's before/after number
-        # (sync engine ~= device time / step; overlap shrinks it)
-        hists = self.obs.registry.histograms
-        step_h = hists.get("phase/step")
-        if step_h is not None and step_h.total > 0:
-            sync_h = hists.get("phase/sync")
-            self.obs.sample("engine", {
-                "bubble_fraction": (sync_h.total / step_h.total)
-                if sync_h is not None else 0.0})
 
     def step(self) -> list[RequestState]:
         """One lockstep engine step: schedule, run prefill chunks + the
@@ -1015,15 +1054,9 @@ class Engine:
         flight reconciles first, so mixed ``step``/``step_async``
         driving stays safe."""
         with self._trace_ctx():
+            mark = self._compile_mark()
             with self._phase("step"):
-                self._fault_tick()
-                self._expire_deadlines()
-                self._degrade_tick()
-                # audit BEFORE dispatch: corruption is caught before the
-                # next step's plan/kernels consume it, so recovery can
-                # still rebuild without a corrupt-table step having
-                # committed wrong tokens (DESIGN.md §14)
-                self._audit_maybe()
+                self._admin()
                 if self._pending is not None:
                     rec, self._pending = self._pending, None
                     self._reconcile(rec)
@@ -1031,6 +1064,7 @@ class Engine:
                 if rec is not None:
                     self._reconcile(rec)
                 self._idle_release_holds()
+                self._note_compiles(mark)
             if self.obs.enabled:
                 self._sample_gauges()
             return rec.running if rec is not None else []
@@ -1046,18 +1080,17 @@ class Engine:
         or possible preemption (``_can_overlap``).  Returns the set the
         *submitted* step runs; its tokens fold one call later."""
         with self._trace_ctx():
+            mark = self._compile_mark()
             with self._phase("step"):
                 out = self._step_async_host()
                 self._idle_release_holds()
+                self._note_compiles(mark)
             if self.obs.enabled:
                 self._sample_gauges()
             return out
 
     def _step_async_host(self) -> list[RequestState]:
-        self._fault_tick()
-        self._expire_deadlines()
-        self._degrade_tick()
-        self._audit_maybe()             # pre-dispatch, as in step()
+        self._admin()                   # pre-dispatch, as in step()
         prev, self._pending = self._pending, None
         if prev is not None and self._can_overlap(prev):
             # the overlap phase measures exactly the host work hidden
@@ -1144,17 +1177,48 @@ class Engine:
         if refusals > self._c["alias_refusals"].value:
             self._c["alias_refusals"].inc(
                 refusals - self._c["alias_refusals"].value)
-        self._note_transitions(plan)
-        if prev is not None:
-            # _can_overlap proved the pool could back every growth
-            assert not plan.preempted, \
-                "overlap gate let a preemption through"
-        running = plan.decode + [s for s, _ in plan.prefill]
-        for s in running:
-            self._admit_step.setdefault(s.req.rid, self._steps)
+        # the pool's side of the plan, before any dispatch reads the pool
+        with self._phase("cow"):
+            self._note_transitions(plan)
+            if prev is not None:
+                # _can_overlap proved the pool could back every growth
+                assert not plan.preempted, \
+                    "overlap gate let a preemption through"
+            running = plan.decode + [s for s, _ in plan.prefill]
+            for s in running:
+                self._admit_step.setdefault(s.req.rid, self._steps)
+            if running:
+                self._pool_moves(plan, spec_k)
         if not running:
             return None
 
+        rec = _Inflight(plan=plan, running=running)
+
+        if plan.prefill:
+            sampled: list[RequestState] = []
+            with self._phase("prefill_dispatch"):
+                self._dispatch_prefill(plan, spec_k, rec.fetch, sampled)
+            rec.pre_rows = [(s, s.slot) for s in sampled]
+
+        if plan.decode:
+            with self._phase("decode_dispatch"):   # plain, or draft+verify
+                self._dispatch_decode(plan, spec_k, rec.fetch,
+                                      rec.spec_meta, prev)
+            if not (spec_k and plan.spec):
+                # fold metadata, captured before anything moves: emit is
+                # sync-fold's "model just saw the last known token" test
+                rec.decode_rows = [(s, s.slot,
+                                    s.num_cached == s.seq_len - 1)
+                                   for s in plan.decode]
+        for s, slot, emit in rec.decode_rows:
+            if emit:
+                rec.src[s.req.rid] = ("dec", slot)
+        for s, slot in rec.pre_rows:
+            rec.src[s.req.rid] = ("pre", slot)
+        return rec
+
+    def _pool_moves(self, plan, spec_k: int) -> None:
+        """Launch the pool copies this plan needs before dispatch."""
         # intra-mesh block migration (DESIGN.md §16) must precede the
         # COW copies and dispatch: a cross-shard alias admitted by this
         # plan is only readable on its new home once the replica copy
@@ -1181,31 +1245,6 @@ class Engine:
                 self.draft_cache = self._cow_fn(
                     self.draft_cache, np.int32(src), np.int32(dst))
             self._c["cow_copies"].inc()
-
-        rec = _Inflight(plan=plan, running=running)
-
-        if plan.prefill:
-            sampled: list[RequestState] = []
-            with self._phase("prefill_dispatch"):
-                self._dispatch_prefill(plan, spec_k, rec.fetch, sampled)
-            rec.pre_rows = [(s, s.slot) for s in sampled]
-
-        if plan.decode:
-            with self._phase("decode_dispatch"):   # plain, or draft+verify
-                self._dispatch_decode(plan, spec_k, rec.fetch,
-                                      rec.spec_meta, prev)
-            if not (spec_k and plan.spec):
-                # fold metadata, captured before anything moves: emit is
-                # sync-fold's "model just saw the last known token" test
-                rec.decode_rows = [(s, s.slot,
-                                    s.num_cached == s.seq_len - 1)
-                                   for s in plan.decode]
-        for s, slot, emit in rec.decode_rows:
-            if emit:
-                rec.src[s.req.rid] = ("dec", slot)
-        for s, slot in rec.pre_rows:
-            rec.src[s.req.rid] = ("pre", slot)
-        return rec
 
     def _reconcile(self, rec: _Inflight, newer: _Inflight | None = None
                    ) -> None:
@@ -1844,6 +1883,11 @@ class Engine:
                 plan, tokens, positions, temps, active, tables,
                 spec_meta)
         else:
+            if self._attn_grid:
+                live, grid = grid_steps(positions + 1, positions,
+                                        tables.shape[1], self.cfg.block_size)
+                self._c["decode_attn_live_steps"].inc(live)
+                self._c["decode_attn_grid_steps"].inc(grid)
             tok = jnp.asarray(tokens)
             for name, mask in feed.items():
                 if mask.any():
@@ -1877,6 +1921,13 @@ class Engine:
             pref_active[s.slot] = True
         ptables = np.where(pref_active[:, None],
                            self.cache_host.tables, 0)
+        self._c["prefill_chunk_tokens"].inc(int(valid.sum()))
+        self._c["prefill_token_slots"].inc(B * C)
+        if self._attn_grid:
+            live, grid = grid_steps(pos[:, 0] + valid, pos[:, 0],
+                                    ptables.shape[1], self.cfg.block_size)
+            self._c["prefill_attn_live_steps"].inc(live)
+            self._c["prefill_attn_grid_steps"].inc(grid)
         args = (jnp.asarray(toks), jnp.asarray(pos),
                 jnp.asarray(np.arange(B, dtype=np.int32)),
                 jnp.asarray(ptables), jnp.asarray(valid))

@@ -342,11 +342,15 @@ def test_run_stats_keys_backward_compatible(key):
 # ---------------------------------------------------------------------------
 
 def _traced_run(key, n=4):
+    """A fresh engine's run with streaming consumers: its first steps
+    compile, and every token goes through an ``on_token`` callback."""
     cfg, m, params = _build(key)
     tel = Telemetry(enabled=True)
     eng = Engine(m, params, ServeConfig(max_seqs=2, block_size=4,
                                         max_len=32), telemetry=tel)
-    _serve(eng, _prompts(cfg, n=n))
+    for p in _prompts(cfg, n=n):
+        eng.add_request(p, max_new_tokens=8, on_token=lambda tok, done: None)
+    eng.run()
     return tel, to_chrome(tel.trace)
 
 
@@ -371,12 +375,17 @@ def test_chrome_trace_schema(key):
 
 def test_chrome_trace_phases_nest_inside_step(key):
     """Chrome nests same-tid X events by time containment: every inner
-    phase slice must sit inside its step's enclosing slice."""
+    phase slice must sit inside its step's enclosing slice — the phases
+    that tile a step, the consumer callbacks (``emit``) and the step's
+    compile seconds (``compile``) alike."""
     _, doc = _traced_run(key)
     xs = [e for e in doc["traceEvents"] if e["ph"] == "X"]
     steps = {e["args"]["step"]: e for e in xs if e["name"] == "step"}
     inner = [e for e in xs if e["name"] != "step"]
     assert steps and inner
+    assert {e["name"] for e in inner} >= {
+        "admin", "plan", "cow", "prefill_dispatch", "decode_dispatch",
+        "sync", "fold", "emit", "compile"}
     eps = 1.0                                    # us; clock granularity
     for e in inner:
         outer = steps[e["args"]["step"]]

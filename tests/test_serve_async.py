@@ -160,8 +160,7 @@ def test_async_spec_decode_falls_back_to_lockstep(mp, key):
 
 def test_async_overlap_engages_and_is_observable(mp):
     """Steady decode with pool headroom must actually take the overlap
-    path (phase/overlap recorded), and the bubble-fraction gauge must be
-    sampled."""
+    path (phase/overlap recorded), and the sync phase must be timed."""
     from repro.obs import Telemetry
     m, params = mp
     tel = Telemetry(enabled=True)
@@ -172,7 +171,7 @@ def test_async_overlap_engages_and_is_observable(mp):
     hists = tel.registry.histograms
     assert hists["phase/overlap"].count > 0
     assert hists["phase/step"].count >= hists["phase/overlap"].count
-    assert 0.0 <= tel.registry.gauges["engine/bubble_fraction"].value <= 1.0
+    assert hists["phase/sync"].count > 0
 
 
 def test_mixed_step_and_step_async_driving(mp):
