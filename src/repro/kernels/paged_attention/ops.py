@@ -24,7 +24,8 @@ active mesh (``distributed.sharding.use_rules(rules, mesh=mesh)``), the
 kernel call wraps itself in ``shard_map`` — sequences split over the
 ``serve_batch`` (data) axis, KV heads over the ``kv_heads`` (model) axis
 — so each device runs the Pallas kernel on its own slice of the block
-pools with its own slots' block tables scalar-prefetched locally.  GSPMD
+pools with its own slots' block tables scalar-prefetched locally (the
+layer axis of stacked pools and the layer index are replicated).  GSPMD
 cannot partition an opaque ``pallas_call``; without the wrap a sharded
 step would all-gather the pools onto every device, which is exactly what
 paging exists to avoid.  Attention needs no cross-device reduction in
@@ -38,6 +39,7 @@ import functools
 import math
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
@@ -73,15 +75,6 @@ def grid_steps(kv_lens, q_starts, num_table_blocks: int, block_size: int,
     return int(np.count_nonzero(live)), kv.shape[0] * num_table_blocks
 
 
-def _positional_scales(fn):
-    """shard_map passes operands positionally; the kernel entry points
-    take the quantization scales keyword-only — adapt."""
-    def wrapped(*args):
-        *rest, ks, vs = args
-        return fn(*rest, k_scale=ks, v_scale=vs)
-    return wrapped
-
-
 def _serve_partition(B: int, H: int, KH: int):
     """(mesh, batch_axes, head_axes) when a serving mesh is active and at
     least one axis can actually split the work; None otherwise.
@@ -113,87 +106,92 @@ def _serve_partition(B: int, H: int, KH: int):
     return mesh, batch_axes, head_axes
 
 
+def _kernel_call(entry, q, k_pool, v_pool, block_tables, rows, *, heads,
+                 layer, k_scale, v_scale, return_visits, **static):
+    """Run kernel ``entry`` on ``(q, k_pool, v_pool, block_tables, *rows)``
+    (``rows``: the per-sequence (B,) operands), wrapped in ``shard_map``
+    when a serving mesh splits the work.  Pools keep every leading axis
+    (layer, block) replicated; ``heads`` is q's head count."""
+    fn = functools.partial(entry, interpret=not _on_tpu(),
+                           return_visits=return_visits, **static)
+    if layer is not None:
+        layer = jnp.asarray(layer, jnp.int32)   # an operand of shard_map
+    kw = {n: a for n, a in (("layer", layer), ("k_scale", k_scale),
+                            ("v_scale", v_scale)) if a is not None}
+    part = _serve_partition(q.shape[0], heads, k_pool.shape[-3])
+    if part is None:
+        return fn(q, k_pool, v_pool, block_tables, *rows, **kw)
+    mesh, bd, hd = part
+    bd, hd = (bd or None), (hd or None)
+    lead = (None,) * (k_pool.ndim - 3)
+    q_spec = P(bd, *(None,) * (q.ndim - 3), hd, None)
+    kw_specs = {"layer": P(), "k_scale": P(*lead, hd, None),
+                "v_scale": P(*lead, hd, None)}
+    in_specs = (q_spec, P(*lead, hd, None, None), P(*lead, hd, None, None),
+                P(bd, None), *(P(bd),) * len(rows),
+                *(kw_specs[n] for n in kw))
+    names = tuple(kw)
+
+    def positional(*args):
+        # shard_map passes operands positionally; the entry points take
+        # the layer index and the scales keyword-only
+        n = len(args) - len(names)
+        return fn(*args[:n], **dict(zip(names, args[n:])))
+
+    return jax.shard_map(
+        positional, mesh=mesh, in_specs=in_specs,
+        out_specs=(q_spec, P(bd, hd)) if return_visits else q_spec,
+        check_vma=False)(q, k_pool, v_pool, block_tables, *rows,
+                         *kw.values())
+
+
 def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
-                    window=0, scale: float | None = None,
+                    layer=None, window=0, scale: float | None = None,
                     use_kernel: bool | None = None,
                     return_visits: bool = False,
                     k_scale=None, v_scale=None):
-    """Decode: q (B, H, D); pools (P, KH, bs, D/DV) -> (B, H, DV).
+    """Decode: q (B, H, D); pools (L, P, KH, bs, D/DV) read at ``layer``
+    (the serving step's layer scan carries them whole), or one layer's
+    (P, KH, bs, D/DV) with no layer -> (B, H, DV).
 
-    ``k_scale``/``v_scale`` (P, KH, bs) mark the pools as quantized: the
-    kernel fuses dequantization into its score and value products; the
-    reference dequantizes the gathered history.  Scale pools shard
+    ``k_scale``/``v_scale`` ((L,) P, KH, bs) mark the pools as quantized:
+    the kernel fuses dequantization into its score and value products;
+    the reference dequantizes the gathered history.  Scale pools shard
     exactly like their KV pools (kv_heads over the model axis) — same
     placement, same shard_map specs minus the head_dim axis."""
     if use_kernel is None:
         use_kernel = _kernel_path(window)
     if use_kernel:
-        fn = functools.partial(paged_attention_kernel, window=window,
-                               scale=scale, interpret=not _on_tpu(),
-                               return_visits=return_visits)
-        part = _serve_partition(q.shape[0], q.shape[1], k_pool.shape[1])
-        if part is not None:
-            mesh, bd, hd = part
-            bd, hd = (bd or None), (hd or None)
-            in_specs = (P(bd, hd, None), P(None, hd, None, None),
-                        P(None, hd, None, None), P(bd, None), P(bd))
-            if k_scale is not None:
-                in_specs += (P(None, hd, None), P(None, hd, None))
-                fn = _positional_scales(fn)
-            fn = jax.shard_map(
-                fn, mesh=mesh, in_specs=in_specs,
-                out_specs=(P(bd, hd, None), P(bd, hd)) if return_visits
-                else P(bd, hd, None),
-                check_vma=False)
-            if k_scale is not None:
-                return fn(q, k_pool, v_pool, block_tables, kv_lens,
-                          k_scale, v_scale)
-            return fn(q, k_pool, v_pool, block_tables, kv_lens)
-        return fn(q, k_pool, v_pool, block_tables, kv_lens,
-                  k_scale=k_scale, v_scale=v_scale)
+        return _kernel_call(
+            paged_attention_kernel, q, k_pool, v_pool, block_tables,
+            (kv_lens,), heads=q.shape[1], layer=layer, k_scale=k_scale,
+            v_scale=v_scale, return_visits=return_visits, window=window,
+            scale=scale)
     if return_visits:
         raise ValueError("visit counts are a kernel-path observable")
     return paged_attention_reference(
-        q, k_pool, v_pool, block_tables, kv_lens,
+        q, k_pool, v_pool, block_tables, kv_lens, layer=layer,
         window=window, scale=scale, k_scale=k_scale, v_scale=v_scale)
 
 
 def paged_prefill_attention(q, k_pool, v_pool, block_tables, q_starts,
-                            kv_lens, *, window=0,
+                            kv_lens, *, layer=None, window=0,
                             scale: float | None = None,
                             use_kernel: bool | None = None,
                             return_visits: bool = False,
                             k_scale=None, v_scale=None):
-    """Chunked prefill: q (B, C, H, D) -> (B, C, H, DV)."""
+    """Chunked prefill: q (B, C, H, D) -> (B, C, H, DV); pools and
+    ``layer`` as in ``paged_attention``."""
     if use_kernel is None:
         use_kernel = _kernel_path(window)
     if use_kernel:
-        fn = functools.partial(paged_prefill_attention_kernel, window=window,
-                               scale=scale, interpret=not _on_tpu(),
-                               return_visits=return_visits)
-        part = _serve_partition(q.shape[0], q.shape[2], k_pool.shape[1])
-        if part is not None:
-            mesh, bd, hd = part
-            bd, hd = (bd or None), (hd or None)
-            in_specs = (P(bd, None, hd, None), P(None, hd, None, None),
-                        P(None, hd, None, None), P(bd, None), P(bd),
-                        P(bd))
-            if k_scale is not None:
-                in_specs += (P(None, hd, None), P(None, hd, None))
-                fn = _positional_scales(fn)
-            fn = jax.shard_map(
-                fn, mesh=mesh, in_specs=in_specs,
-                out_specs=(P(bd, None, hd, None), P(bd, hd))
-                if return_visits else P(bd, None, hd, None),
-                check_vma=False)
-            if k_scale is not None:
-                return fn(q, k_pool, v_pool, block_tables, q_starts,
-                          kv_lens, k_scale, v_scale)
-            return fn(q, k_pool, v_pool, block_tables, q_starts, kv_lens)
-        return fn(q, k_pool, v_pool, block_tables, q_starts, kv_lens,
-                  k_scale=k_scale, v_scale=v_scale)
+        return _kernel_call(
+            paged_prefill_attention_kernel, q, k_pool, v_pool, block_tables,
+            (q_starts, kv_lens), heads=q.shape[2], layer=layer,
+            k_scale=k_scale, v_scale=v_scale, return_visits=return_visits,
+            window=window, scale=scale)
     if return_visits:
         raise ValueError("visit counts are a kernel-path observable")
     return paged_prefill_attention_reference(
-        q, k_pool, v_pool, block_tables, q_starts, kv_lens,
+        q, k_pool, v_pool, block_tables, q_starts, kv_lens, layer=layer,
         window=window, scale=scale, k_scale=k_scale, v_scale=v_scale)

@@ -6,23 +6,28 @@ tile per sequence, and each query row r masks against its absolute
 position ``q_start + r // G`` — prefill-aware causal masking inside the
 online-softmax loop.
 
-Pool layout is head-major within a block: K/V pools are (P, KH, bs, D)
-and scale pools (P, KH, bs).  One block of one sequence is therefore a
-single contiguous (KH, bs, D) DMA, each head's tile is a clean (bs, D)
-slab, and each head's scales are a (1, bs) lane row — the shapes whose
-trailing two dimensions equal the array's, which is what the TPU
-compiler accepts for BlockSpecs that are not (8, 128)-aligned.
+Pool layout is head-major within a block: K/V pools are (L, P, KH, bs,
+D) and scale pools (L, P, KH, bs), every layer's pool stacked, and the
+kernel reads the one at its ``layer`` index (a scalar-prefetch operand).
+The serving step carries the stacked pool through its layer scan and
+hands it here whole, so no layer's pool is ever sliced out and copied
+(DESIGN.md §8).  One block of one sequence is a single contiguous (KH,
+bs, D) DMA, each head's tile is a clean (bs, D) slab, and each head's
+scales are a (1, bs) lane row — the shapes whose trailing two dimensions
+equal the array's, which is what the TPU compiler accepts for
+BlockSpecs that are not (8, 128)-aligned; the layer axis is squeezed.
 
 Grid (B, NB); the block dimension is innermost so the f32 online-softmax
 accumulators (acc, running max m, running sum l, one set per KV head)
 persist in VMEM scratch across the KV blocks of one sequence.  Every KV
 head is processed in the same grid step, so each pool block is read from
-HBM exactly once per sequence.  The block table, per-sequence lengths
-and query start positions ride in as *scalar prefetch* operands
-(``pltpu.PrefetchScalarGridSpec``): the K/V BlockSpec index maps read
-``tables[b, j]`` to DMA the j-th logical block of sequence b from
-wherever it lives in the pool — the gathered (B, S, KH, D) history is
-never materialized, which is the whole point of paging.
+HBM exactly once per sequence.  The block table, per-sequence lengths,
+query start positions and the layer index ride in as *scalar prefetch*
+operands (``pltpu.PrefetchScalarGridSpec``): the K/V BlockSpec index
+maps read ``layer`` and ``tables[b, j]`` to DMA the j-th logical block
+of sequence b from wherever it lives in that layer's pool — the gathered
+(B, S, KH, D) history is never materialized, which is the whole point of
+paging.
 
 Fully-masked blocks are skipped: table entries past a sequence's length
 (``j * block_size >= kv_len``) and, under a sliding window, blocks wholly
@@ -65,9 +70,10 @@ def _block_live(j, kv_len, q_start, *, window: int, block_size: int):
     return live
 
 
-def _kernel(lens_ref, starts_ref, tables_ref, q_ref, k_ref, v_ref, *refs,
-            scale: float, window: int, block_size: int, group: int,
-            quantized: bool):
+def _kernel(lens_ref, starts_ref, tables_ref, layer_ref, q_ref, k_ref,
+            v_ref, *refs, scale: float, window: int, block_size: int,
+            group: int, quantized: bool):
+    del layer_ref                 # addresses the pools in the index maps
     if quantized:
         ks_ref, vs_ref, o_ref, visits_ref, acc_ref, m_ref, l_ref, \
             cnt_ref = refs
@@ -133,17 +139,24 @@ def _kernel(lens_ref, starts_ref, tables_ref, q_ref, k_ref, v_ref, *refs,
 
 def _paged_attention(q, k_pool, v_pool, block_tables, q_starts, kv_lens, *,
                      window: int, scale: float | None, interpret: bool,
-                     k_scale=None, v_scale=None):
-    """q (B, C, H, D); pools (P, KH, bs, D/DV); tables (B, NB);
-    q_starts/kv_lens (B,); k/v_scale (P, KH, bs) f32 when the pools are
+                     layer=None, k_scale=None, v_scale=None):
+    """q (B, C, H, D); pools (L, P, KH, bs, D/DV) read at ``layer`` (an
+    int32 scalar, traced or not), or one layer's (P, KH, bs, D/DV) when
+    ``layer`` is None; tables (B, NB); q_starts/kv_lens (B,);
+    k/v_scale (L, P, KH, bs) (or (P, KH, bs)) f32 when the pools are
     quantized.  Returns (out (B, C, H, DV), visits (B, KH))."""
+    quantized = k_scale is not None
+    if layer is None:
+        k_pool, v_pool = k_pool[None], v_pool[None]
+        if quantized:
+            k_scale, v_scale = k_scale[None], v_scale[None]
+        layer = 0
     B, C, H, D = q.shape
-    KH, bs, DV = k_pool.shape[1], k_pool.shape[2], v_pool.shape[3]
+    KH, bs, DV = k_pool.shape[2], k_pool.shape[3], v_pool.shape[4]
     NB = block_tables.shape[1]
     G = H // KH
     CG = C * G
     scale = scale if scale is not None else D ** -0.5
-    quantized = k_scale is not None
 
     # (B, C, KH, G, D) -> (B, KH, C*G, D): row r is query (r // G, r % G)
     qg = q.reshape(B, C, KH, G, D).transpose(0, 2, 1, 3, 4) \
@@ -151,37 +164,36 @@ def _paged_attention(q, k_pool, v_pool, block_tables, q_starts, kv_lens, *,
     kernel = functools.partial(_kernel, scale=scale, window=window,
                                block_size=bs, group=G, quantized=quantized)
 
-    def _block(b, j, lens, starts, tables):
+    def _block(b, j, lens, starts, tables, layer_idx):
         # skip the DMA for fully-masked blocks — past the sequence's
         # length, or wholly left of the sliding window: read null block 0
+        # of the same layer
         live = _block_live(j, lens[b], starts[b], window=window,
                            block_size=bs)
-        return jnp.where(live, tables[b, j], 0)
+        return layer_idx[0], jnp.where(live, tables[b, j], 0)
 
     in_specs = [
-        pl.BlockSpec((1, KH, CG, D),
-                     lambda b, j, lens, starts, tables: (b, 0, 0, 0)),
-        pl.BlockSpec((1, KH, bs, D),
-                     lambda *a: (_block(*a), 0, 0, 0)),
-        pl.BlockSpec((1, KH, bs, DV),
-                     lambda *a: (_block(*a), 0, 0, 0)),
+        pl.BlockSpec((1, KH, CG, D), lambda b, *_: (b, 0, 0, 0)),
+        pl.BlockSpec((None, 1, KH, bs, D),
+                     lambda *a: (*_block(*a), 0, 0, 0)),
+        pl.BlockSpec((None, 1, KH, bs, DV),
+                     lambda *a: (*_block(*a), 0, 0, 0)),
     ]
     operands = [qg, k_pool, v_pool]
     if quantized:
         # scales ride as two extra operands addressed by the same index
         # map as their pools, so a skipped KV DMA skips its scales too
-        in_specs += [pl.BlockSpec((1, KH, bs), lambda *a: (_block(*a), 0, 0))
-                     ] * 2
+        in_specs += [pl.BlockSpec((None, 1, KH, bs),
+                                  lambda *a: (*_block(*a), 0, 0))] * 2
         operands += [k_scale.astype(jnp.float32),
                      v_scale.astype(jnp.float32)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B, NB),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, KH, CG, DV),
-                         lambda b, j, lens, starts, tables: (b, 0, 0, 0)),
+            pl.BlockSpec((1, KH, CG, DV), lambda b, *_: (b, 0, 0, 0)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         scratch_shapes=[
@@ -199,7 +211,8 @@ def _paged_attention(q, k_pool, v_pool, block_tables, q_starts, kv_lens, *,
         interpret=interpret,
         name="paged_attention",
     )(kv_lens.astype(jnp.int32), q_starts.astype(jnp.int32),
-      block_tables.astype(jnp.int32), *operands)
+      block_tables.astype(jnp.int32),
+      jnp.reshape(layer, (1,)).astype(jnp.int32), *operands)
     out = out.reshape(B, KH, C, G, DV).transpose(0, 2, 1, 3, 4) \
         .reshape(B, C, H, DV)
     # every KV head of a sequence visits the same blocks
@@ -207,21 +220,24 @@ def _paged_attention(q, k_pool, v_pool, block_tables, q_starts, kv_lens, *,
 
 
 def paged_attention_kernel(q, k_pool, v_pool, block_tables, kv_lens, *,
-                           window: int = 0, scale: float | None = None,
+                           layer=None, window: int = 0,
+                           scale: float | None = None,
                            interpret: bool = True,
                            return_visits: bool = False,
                            k_scale=None, v_scale=None):
-    """Decode entry point: q (B, H, D), one query token at ``kv_len - 1``."""
+    """Decode entry point: q (B, H, D), one query token at ``kv_len - 1``;
+    pools and ``layer`` as in ``_paged_attention``."""
     out, visits = _paged_attention(
         q[:, None], k_pool, v_pool, block_tables, kv_lens - 1, kv_lens,
-        window=window, scale=scale, interpret=interpret,
+        layer=layer, window=window, scale=scale, interpret=interpret,
         k_scale=k_scale, v_scale=v_scale)
     out = out[:, 0]
     return (out, visits) if return_visits else out
 
 
 def paged_prefill_attention_kernel(q, k_pool, v_pool, block_tables,
-                                   q_starts, kv_lens, *, window: int = 0,
+                                   q_starts, kv_lens, *, layer=None,
+                                   window: int = 0,
                                    scale: float | None = None,
                                    interpret: bool = True,
                                    return_visits: bool = False,
@@ -231,6 +247,6 @@ def paged_prefill_attention_kernel(q, k_pool, v_pool, block_tables,
     valid count produce garbage the caller discards)."""
     out, visits = _paged_attention(
         q, k_pool, v_pool, block_tables, q_starts, kv_lens,
-        window=window, scale=scale, interpret=interpret,
+        layer=layer, window=window, scale=scale, interpret=interpret,
         k_scale=k_scale, v_scale=v_scale)
     return (out, visits) if return_visits else out

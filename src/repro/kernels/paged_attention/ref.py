@@ -5,17 +5,23 @@ non-contiguous fixed-size blocks of a shared pool, addressed through a
 per-sequence block table (vLLM-style paging).
 
 Shapes:
-  q            (B, H, D)         one decode token per sequence, H = KH * G
-  k_pool       (P, KH, bs, D)    shared block pool (P blocks of bs tokens,
-  v_pool       (P, KH, bs, DV)   head-major within a block)
-  block_tables (B, NB) int32     pool index of each logical block
-  kv_lens      (B,)    int32     valid tokens per sequence (incl. current)
-  window       int | (B,) array  0 = full causal; >0 = sliding window
-  k/v_scale    (P, KH, bs) f32   per-write dequant scales when the pools
-                                 are quantized (int8 / fp8-e4m3)
+  q            (B, H, D)           one decode token per sequence,
+                                   H = KH * G
+  k_pool       (L, P, KH, bs, D)   every layer's block pool stacked (P
+  v_pool       (L, P, KH, bs, DV)  blocks of bs tokens, head-major within
+                                   a block), read at ``layer``; with no
+                                   layer, one layer's (P, KH, bs, D/DV)
+  block_tables (B, NB) int32       pool index of each logical block
+  kv_lens      (B,)    int32       valid tokens per sequence (incl.
+                                   current)
+  layer        int32 scalar | None the layer whose pool is read
+  window       int | (B,) array    0 = full causal; >0 = sliding window
+  k/v_scale    (L, P, KH, bs) f32  per-write dequant scales when the
+                                   pools are quantized (int8 / fp8-e4m3)
 
-Output (B, H, DV).  The reference materializes the gathered history
-(B, NB*bs, KH, D); the Pallas kernel never does.
+Output (B, H, DV).  The reference gathers ``pool[layer, block_tables]``,
+materializing that layer's history (B, NB*bs, KH, D) and nothing more;
+the Pallas kernel never materializes it.
 """
 from __future__ import annotations
 
@@ -27,22 +33,25 @@ from repro.kernels.paged_attention.quant import dequantize
 NEG_INF = -1e30
 
 
-def _gather_kv(pool, block_tables, scale):
-    """Gather (B, S, KH, d) history from the pool, dequantizing with the
-    identically-gathered scales when given — the same bytes->values rule
-    as the kernel's fused dequant, applied after materialization."""
+def _gather_kv(pool, block_tables, scale, layer):
+    """Gather (B, S, KH, d) history from ``layer``'s pool, dequantizing
+    with the identically-gathered scales when given — the same
+    bytes->values rule as the kernel's fused dequant, applied after
+    materialization."""
     B, NB = block_tables.shape
-    KH, bs = pool.shape[1], pool.shape[2]
-    out = pool[block_tables].transpose(0, 1, 3, 2, 4) \
-        .reshape(B, NB * bs, KH, -1)
+    at = block_tables if layer is None else (layer, block_tables)
+    out = pool[at]                                  # (B, NB, KH, bs, d)
+    KH, bs = out.shape[2], out.shape[3]
+    out = out.transpose(0, 1, 3, 2, 4).reshape(B, NB * bs, KH, -1)
     if scale is not None:
-        out = dequantize(out, scale[block_tables].transpose(0, 1, 3, 2)
+        out = dequantize(out, scale[at].transpose(0, 1, 3, 2)
                          .reshape(B, NB * bs, KH))
     return out
 
 
 def paged_prefill_attention_reference(q, k_pool, v_pool, block_tables,
-                                      q_starts, kv_lens, *, window=0,
+                                      q_starts, kv_lens, *, layer=None,
+                                      window=0,
                                       scale: float | None = None,
                                       k_scale=None, v_scale=None
                                       ) -> jax.Array:
@@ -52,13 +61,13 @@ def paged_prefill_attention_reference(q, k_pool, v_pool, block_tables,
     sequence's valid count produce garbage the caller discards.  Output
     (B, C, H, DV)."""
     B, C, H, D = q.shape
-    KH, bs = k_pool.shape[1], k_pool.shape[2]
+    KH, bs = k_pool.shape[-3], k_pool.shape[-2]
     NB = block_tables.shape[1]
     G = H // KH
     scale = scale if scale is not None else D ** -0.5
 
-    k = _gather_kv(k_pool, block_tables, k_scale)           # (B, S, KH, D)
-    v = _gather_kv(v_pool, block_tables, v_scale)
+    k = _gather_kv(k_pool, block_tables, k_scale, layer)  # (B, S, KH, D)
+    v = _gather_kv(v_pool, block_tables, v_scale, layer)
 
     qg = q.reshape(B, C, KH, G, D)
     s = jnp.einsum("bqkgd,bskd->bkgqs", qg.astype(jnp.float32),
@@ -79,16 +88,17 @@ def paged_prefill_attention_reference(q, k_pool, v_pool, block_tables,
 
 
 def paged_attention_reference(q, k_pool, v_pool, block_tables, kv_lens, *,
-                              window=0, scale: float | None = None,
+                              layer=None, window=0,
+                              scale: float | None = None,
                               k_scale=None, v_scale=None) -> jax.Array:
     B, H, D = q.shape
-    KH, bs = k_pool.shape[1], k_pool.shape[2]
+    KH, bs = k_pool.shape[-3], k_pool.shape[-2]
     NB = block_tables.shape[1]
     G = H // KH
     scale = scale if scale is not None else D ** -0.5
 
-    k = _gather_kv(k_pool, block_tables, k_scale)          # (B, S, KH, D)
-    v = _gather_kv(v_pool, block_tables, v_scale)
+    k = _gather_kv(k_pool, block_tables, k_scale, layer)  # (B, S, KH, D)
+    v = _gather_kv(v_pool, block_tables, v_scale, layer)
 
     qg = q.reshape(B, KH, G, D)
     s = jnp.einsum("bkgd,bskd->bkgs", qg.astype(jnp.float32),

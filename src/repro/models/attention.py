@@ -159,33 +159,38 @@ def attention_decode(params: dict, cfg, x: jax.Array, pos: jax.Array,
     return out, KVCache(ck, cv)
 
 
-def _scatter_kv(kv: dict, k_new, v_new, block_tables, positions,
+def _scatter_kv(kv: dict, layer, k_new, v_new, block_tables, positions,
                 inchunk=None) -> dict:
-    """Scatter per-token K/V (B, C, KH, hd) into the pool blocks their
-    absolute ``positions`` (B, C) map to through ``block_tables`` (B, NB).
+    """Scatter one layer's per-token K/V (B, C, KH, hd) into the stacked
+    pool at ``layer`` and the blocks their absolute ``positions`` (B, C)
+    map to through ``block_tables`` (B, NB).
 
-    ``kv`` is one layer's pool slice: ``{"k", "v"}`` plus, when the pool
-    is quantized, ``{"k_scale", "v_scale"}`` (P, KH, bs) f32.  ``inchunk``
-    (B, C) bool masks padding: masked tokens (and positions pointing past
-    the table) are redirected to the reserved null block 0, where writes
-    are harmless by construction.  Shared by the paged decode,
-    chunked-prefill and speculative draft/verify paths, so the "where
-    does a token's KV land — and what bytes does it land as" rule exists
-    exactly once.  Plain narrow pools cast on write (a draft pool may be
-    allocated narrower than the compute dtype —
-    ``ServeConfig.draft_cache_dtype``); quantized pools quantize
-    symmetrically on write, storing the per-(token, kv-head) scale at the
-    same (block, offset) coordinates (DESIGN.md §11)."""
+    ``kv`` is the stacked pool that rides the layer scan's carry:
+    ``{"k", "v"}`` (L, P, KH, bs, hd/vhd) plus, when the pool is
+    quantized, ``{"k_scale", "v_scale"}`` (L, P, KH, bs) f32.  Each leaf
+    takes one scatter at (layer, block, head, offset) per token, which
+    XLA updates in place in the loop carry: no layer's pool is sliced out
+    or stacked back.  ``inchunk`` (B, C) bool masks padding: masked
+    tokens (and positions pointing past the table) are redirected to the
+    layer's reserved null block 0, where writes are harmless by
+    construction.  Shared by the paged decode, chunked-prefill and
+    speculative draft/verify paths, so the "where does a token's KV land
+    — and what bytes does it land as" rule exists exactly once.  Plain
+    narrow pools cast on write (a draft pool may be allocated narrower
+    than the compute dtype — ``ServeConfig.draft_cache_dtype``);
+    quantized pools quantize symmetrically on write, storing the
+    per-(token, kv-head) scale at the same (layer, block, offset)
+    coordinates (DESIGN.md §11)."""
     k_pool, v_pool = kv["k"], kv["v"]
-    KH, bs, NB = k_pool.shape[1], k_pool.shape[2], block_tables.shape[1]
+    KH, bs, NB = k_pool.shape[2], k_pool.shape[3], block_tables.shape[1]
     blk_idx = jnp.clip(positions // bs, 0, NB - 1)
     blk = jnp.take_along_axis(block_tables, blk_idx, axis=1)
     off = positions % bs
     if inchunk is not None:
         blk = jnp.where(inchunk, blk, 0)
         off = jnp.where(inchunk, off, 0)
-    # pools are head-major within a block: (block, head, offset) per token
-    at = (blk[..., None], jnp.arange(KH), off[..., None])
+    # pools are head-major within a block: (layer, block, head, offset)
+    at = (layer, blk[..., None], jnp.arange(KH), off[..., None])
     if "k_scale" in kv:
         from repro.kernels.paged_attention import quantize
         qk, sk = quantize(k_new, k_pool.dtype)
@@ -199,37 +204,41 @@ def _scatter_kv(kv: dict, k_new, v_new, block_tables, positions,
 
 
 def attention_paged_decode(params: dict, cfg, x: jax.Array,
-                           positions: jax.Array, kv: dict,
+                           positions: jax.Array, kv: dict, layer,
                            block_tables: jax.Array,
                            window=0) -> tuple[jax.Array, dict]:
     """One-token decode over a paged KV pool (continuous batching).
 
     x (B,1,d); positions (B,) int32 — per-sequence write index (sequences in
     a serving batch are at *different* depths, unlike ``attention_decode``'s
-    single scalar pos).  ``kv`` is one layer's pool slice ``{"k", "v"}``
-    (P, KH, bs, hd/vhd), plus ``{"k_scale", "v_scale"}`` when quantized;
-    block_tables (B, NB) maps logical to pool blocks.  window: python int
-    for static masking (the kernel on TPU) or a (B,) array for
-    per-sequence dynamic windows (hybrid layers; reference path).
+    single scalar pos).  ``kv`` is the stacked pool ``{"k", "v"}``
+    (L, P, KH, bs, hd/vhd), plus ``{"k_scale", "v_scale"}`` when
+    quantized, carried whole through the layer scan; ``layer`` (int32
+    scalar, traced or not) is this layer's index into it: the new token
+    is scattered there and the attention reads it there.  block_tables
+    (B, NB) maps logical to pool blocks.  window: python int for static
+    masking (the kernel on TPU) or a (B,) array for per-sequence dynamic
+    windows (hybrid layers; reference path).
 
-    Returns (out (B,1,d), new kv dict).
+    Returns (out (B,1,d), new stacked kv dict).
     """
     from repro.kernels.paged_attention import paged_attention
 
     B = x.shape[0]
     q, k_new, v_new = _qkv(params, cfg, x, positions[:, None])
-    kv = _scatter_kv(kv, k_new, v_new, block_tables, positions[:, None])
+    kv = _scatter_kv(kv, layer, k_new, v_new, block_tables,
+                     positions[:, None])
     qf = q.reshape(B, q.shape[2] * q.shape[3], q.shape[4])
     o = paged_attention(qf, kv["k"], kv["v"], block_tables, positions + 1,
-                        window=window, k_scale=kv.get("k_scale"),
-                        v_scale=kv.get("v_scale"))
+                        layer=layer, window=window,
+                        k_scale=kv.get("k_scale"), v_scale=kv.get("v_scale"))
     o = o[:, None]                                       # (B, 1, H, vhd)
     out = jnp.einsum("bshk,hkd->bsd", o, params["wo"])
     return out, kv
 
 
 def attention_paged_prefill(params: dict, cfg, x: jax.Array,
-                            positions: jax.Array, kv: dict,
+                            positions: jax.Array, kv: dict, layer,
                             block_tables: jax.Array,
                             valid: jax.Array, window=0
                             ) -> tuple[jax.Array, dict]:
@@ -238,25 +247,27 @@ def attention_paged_prefill(params: dict, cfg, x: jax.Array,
     x (B, C, d) — a fixed-size chunk of tokens per sequence, right-padded;
     positions (B, C) absolute write indices (``chunk_start + arange(C)``);
     valid (B,) real-token counts.  K/V of the valid tokens are scattered
-    into the pool blocks their positions map to (padding scatters into
-    the reserved null block 0), then the chunk's queries attend causally
-    over the *pool* history — which includes any prefix blocks aliased in
-    by prefix caching.  The per-row absolute-position masking makes the
-    same path serve speculative verify chunks (``[sampled token, K
-    drafts]``): each drafted query sees exactly the history a one-token
-    decode at its position would see.  ``kv``/window as in
-    ``attention_paged_decode``.  Returns (out (B, C, d), new kv dict).
+    into ``layer``'s pool blocks their positions map to (padding scatters
+    into the reserved null block 0), then the chunk's queries attend
+    causally over that layer's *pool* history — which includes any prefix
+    blocks aliased in by prefix caching.  The per-row absolute-position
+    masking makes the same path serve speculative verify chunks
+    (``[sampled token, K drafts]``): each drafted query sees exactly the
+    history a one-token decode at its position would see.  ``kv``,
+    ``layer`` and window as in ``attention_paged_decode``.  Returns
+    (out (B, C, d), new stacked kv dict).
     """
     from repro.kernels.paged_attention import paged_prefill_attention
 
     B, C, _ = x.shape
     q, k_new, v_new = _qkv(params, cfg, x, positions)
     inchunk = jnp.arange(C)[None, :] < valid[:, None]
-    kv = _scatter_kv(kv, k_new, v_new, block_tables, positions, inchunk)
+    kv = _scatter_kv(kv, layer, k_new, v_new, block_tables, positions,
+                     inchunk)
     qf = q.reshape(B, C, q.shape[2] * q.shape[3], q.shape[4])
     o = paged_prefill_attention(
         qf, kv["k"], kv["v"], block_tables, positions[:, 0],
-        positions[:, 0] + valid, window=window,
+        positions[:, 0] + valid, layer=layer, window=window,
         k_scale=kv.get("k_scale"), v_scale=kv.get("v_scale"))
     out = jnp.einsum("bshk,hkd->bsd", o, params["wo"])
     return out, kv

@@ -333,17 +333,27 @@ def paged_cache_axes(cfg: ArchConfig, quantized: bool = False) -> dict:
     return axes
 
 
-def _decode_layer(lp: dict, lc: dict, flag, h: jax.Array, cfg: ArchConfig,
-                  attn_fn, ssm_fn, moe_mask=None) -> tuple[jax.Array, dict]:
+# the KV leaves, stacked over layers, that ride the layer loop's carry
+# (scale pools exist only when the pool is quantized —
+# ServeConfig.cache_dtype, DESIGN.md §11)
+_KV_POOL_KEYS = ("k", "v", "k_scale", "v_scale")
+
+
+def _decode_layer(lp: dict, lc: dict, kv: dict, layer, flag, h: jax.Array,
+                  cfg: ArchConfig, attn_fn, ssm_fn, moe_mask=None
+                  ) -> tuple[jax.Array, dict, dict]:
     """One incremental layer, shared by the contiguous decode, paged decode
     and chunked paged-prefill paths.
 
-    ``attn_fn(attn_params, hn, lc, flag) -> (a_out, kv_out_cache)`` and
-    ``ssm_fn(ssm_params, hn, lc) -> (delta, SSMCache)`` encapsulate
-    everything the cache layouts / step widths disagree on; the
-    residual/FFN scaffolding stays single-source.  ``moe_mask`` (B, S)
-    marks real tokens for expert dispatch — fixed-shape serving batches
-    carry padding that must not consume expert capacity (moe.moe_block).
+    ``attn_fn(attn_params, hn, kv, layer, flag) -> (a_out, kv)`` reads and
+    writes the stacked KV leaves ``kv`` at index ``layer``, and
+    ``ssm_fn(ssm_params, hn, lc) -> (delta, SSMCache)`` advances this
+    layer's recurrent state ``lc``; the two encapsulate everything the
+    cache layouts / step widths disagree on, and the residual/FFN
+    scaffolding stays single-source.  ``moe_mask`` (B, S) marks real
+    tokens for expert dispatch — fixed-shape serving batches carry
+    padding that must not consume expert capacity (moe.moe_block).
+    Returns (h, kv, this layer's new recurrent state).
     """
     out_cache: dict[str, Any] = {}
     hn = rms_norm(h, lp["ln1"], cfg.norm_eps)
@@ -351,15 +361,14 @@ def _decode_layer(lp: dict, lc: dict, flag, h: jax.Array, cfg: ArchConfig,
         delta, new_sc = ssm_fn(lp["ssm"], hn, lc)
         h = h + delta
         out_cache["conv"], out_cache["state"] = new_sc.conv, new_sc.state
-        return h, out_cache
-    a_out, kv_out = attn_fn(lp["attn"], hn, lc, flag)
+        return h, kv, out_cache
+    a_out, kv = attn_fn(lp["attn"], hn, kv, layer, flag)
     if cfg.hybrid:
         s_out, new_sc = ssm_fn(lp["ssm"], hn, lc)
         h = h + a_out + s_out
         out_cache["conv"], out_cache["state"] = new_sc.conv, new_sc.state
     else:
         h = h + a_out
-    out_cache.update(kv_out)
     if cfg.n_experts:
         h2 = rms_norm(h, lp["ln2"], cfg.norm_eps)
         m_out, _ = moe_mod.moe_block(lp["moe"], cfg, h2,
@@ -368,7 +377,7 @@ def _decode_layer(lp: dict, lc: dict, flag, h: jax.Array, cfg: ArchConfig,
     elif cfg.d_ff:
         h2 = rms_norm(h, lp["ln2"], cfg.norm_eps)
         h = h + swiglu(lp["mlp"], h2)
-    return h, out_cache
+    return h, kv, out_cache
 
 
 def _run_decode_layers(params: dict, cfg: ArchConfig, cache: dict,
@@ -376,26 +385,41 @@ def _run_decode_layers(params: dict, cfg: ArchConfig, cache: dict,
                        ) -> tuple[jax.Array, dict]:
     """Scan/unrolled layer loop + final norm shared by the incremental
     paths.  Returns (hidden (B, S, d), new cache); callers project the
-    position(s) they need to logits."""
+    position(s) they need to logits.
+
+    The KV leaves (``_KV_POOL_KEYS``) ride the loop carry whole, stacked
+    (L, ...), beside the hidden state, and each layer reads and writes
+    them at its own index (``jnp.arange(L)`` in the scan's xs, a python
+    int unrolled): as scan xs/ys, XLA would copy every layer's slice out
+    and back, and the whole pool once more, every step (DESIGN.md §8).
+    The small per-slot recurrent state (``conv``, ``state``) rides
+    xs/ys."""
     flags = _is_global_flags(cfg)
+    kv = {n: cache[n] for n in _KV_POOL_KEYS if n in cache}
+    per_layer = {n: a for n, a in cache.items() if n not in kv}
 
     def body(carry, xs):
-        lp, lc, flag = xs
-        return _decode_layer(lp, lc, flag, carry, cfg, attn_fn, ssm_fn,
-                             moe_mask=moe_mask)
+        h, kv = carry
+        lp, lc, flag, layer = xs
+        h, kv, oc = _decode_layer(lp, lc, kv, layer, flag, h, cfg, attn_fn,
+                                  ssm_fn, moe_mask=moe_mask)
+        return (h, kv), oc
 
     if cfg.use_scan:
-        h, new_cache = jax.lax.scan(body, x, (params["layers"], cache, flags))
+        (h, kv), new_cache = jax.lax.scan(
+            body, (x, kv), (params["layers"], per_layer, flags,
+                            jnp.arange(cfg.num_layers)))
     else:
         h = x
         per_layer_caches = []
         for i in range(cfg.num_layers):
             lp = jax.tree.map(lambda a, i=i: a[i], params["layers"])
-            lc = jax.tree.map(lambda a, i=i: a[i], cache)
-            h, oc = body(h, (lp, lc, flags[i]))
+            lc = jax.tree.map(lambda a, i=i: a[i], per_layer)
+            (h, kv), oc = body((h, kv), (lp, lc, flags[i], i))
             per_layer_caches.append(oc)
         new_cache = jax.tree.map(lambda *xs: jnp.stack(xs), *per_layer_caches)
-    return rms_norm(h, params["final_norm"], cfg.norm_eps), new_cache
+    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
+    return h, {**new_cache, **kv}
 
 
 def decode_step(params: dict, cfg: ArchConfig, cache: dict,
@@ -406,8 +430,8 @@ def decode_step(params: dict, cfg: ArchConfig, cache: dict,
     """
     x = jnp.take(params["tok_embed"], tokens[:, None], axis=0)  # (B,1,d)
 
-    def attn_fn(ap, hn, lc, flag):
-        kvc = attn.KVCache(lc["k"], lc["v"])
+    def attn_fn(ap, hn, kv, layer, flag):
+        kvc = attn.KVCache(kv["k"][layer], kv["v"][layer])
         if cfg.hybrid:
             win = jnp.where(flag, jnp.int32(2**30),
                             jnp.int32(cfg.sliding_window))
@@ -416,7 +440,8 @@ def decode_step(params: dict, cfg: ArchConfig, cache: dict,
         else:
             a_out, new_kv = attn.attention_decode(
                 ap, cfg, hn, pos, kvc, "causal")
-        return a_out, {"k": new_kv.k, "v": new_kv.v}
+        return a_out, {"k": kv["k"].at[layer].set(new_kv.k),
+                       "v": kv["v"].at[layer].set(new_kv.v)}
 
     def ssm_fn(sp, hn, lc):
         return ssm_mod.ssm_decode(sp, cfg, hn,
@@ -429,11 +454,6 @@ def decode_step(params: dict, cfg: ArchConfig, cache: dict,
 # ---------------------------------------------------------------------------
 # Paged decode (continuous-batching serving; see DESIGN.md §8)
 # ---------------------------------------------------------------------------
-
-# one layer's KV-pool leaves, in cache-dict order (scale pools exist only
-# when the pool is quantized — ServeConfig.cache_dtype, DESIGN.md §11)
-_KV_POOL_KEYS = ("k", "v", "k_scale", "v_scale")
-
 
 def init_paged_cache(cfg: ArchConfig, num_blocks: int, block_size: int,
                      max_seqs: int, dtype: str | None = None) -> dict:
@@ -500,17 +520,15 @@ def paged_decode_step(params: dict, cfg: ArchConfig, cache: dict,
     # zeroed before use — KV needs no such reset, reads are length-masked
     fresh = positions == 0
 
-    def attn_fn(ap, hn, lc, flag):
+    def attn_fn(ap, hn, kv, layer, flag):
         if cfg.hybrid:
             win = jnp.where(flag, jnp.int32(2**30),
                             jnp.int32(cfg.sliding_window))
             win = jnp.broadcast_to(win, (B,))    # dynamic -> reference path
         else:
             win = 0
-        kv = {n: lc[n] for n in _KV_POOL_KEYS if n in lc}
-        a_out, kv = attn.attention_paged_decode(
-            ap, cfg, hn, positions, kv, block_tables, window=win)
-        return a_out, kv
+        return attn.attention_paged_decode(
+            ap, cfg, hn, positions, kv, layer, block_tables, window=win)
 
     def ssm_fn(sp, hn, lc):
         sc = ssm_mod.SSMCache(
@@ -543,17 +561,16 @@ def _paged_chunk_forward(params: dict, cfg: ArchConfig, cache: dict,
     fresh = positions[:, 0] == 0      # first chunk: reset recurrent state
     inchunk = jnp.arange(C)[None, :] < valid[:, None]           # real tokens
 
-    def attn_fn(ap, hn, lc, flag):
+    def attn_fn(ap, hn, kv, layer, flag):
         if cfg.hybrid:
             win = jnp.where(flag, jnp.int32(2**30),
                             jnp.int32(cfg.sliding_window))
             win = jnp.broadcast_to(win, (B,))    # dynamic -> reference path
         else:
             win = 0
-        kv = {n: lc[n] for n in _KV_POOL_KEYS if n in lc}
-        a_out, kv = attn.attention_paged_prefill(
-            ap, cfg, hn, positions, kv, block_tables, valid, window=win)
-        return a_out, kv
+        return attn.attention_paged_prefill(
+            ap, cfg, hn, positions, kv, layer, block_tables, valid,
+            window=win)
 
     def ssm_fn(sp, hn, lc):
         conv = jnp.where(fresh[:, None, None], 0, lc["conv"][slots])

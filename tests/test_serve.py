@@ -439,3 +439,35 @@ def test_engine_serves_pruned_model(key):
     rid = eng.add_request(prompt, max_new_tokens=6)
     out, _ = eng.run()
     assert out[rid].tokens == list(ref[0, len(prompt):])
+
+
+@pytest.mark.parametrize("name,path,cache_dtype", [
+    ("tinyllama-1.1b", "reference", ""),
+    ("tinyllama-1.1b", "kernel", ""),
+    ("tinyllama-1.1b", "kernel", "int8"),
+    ("hymba-1.5b", "reference", ""),
+])
+def test_engine_scan_and_unrolled_layers_agree(name, path, cache_dtype, key,
+                                               monkeypatch):
+    """The layer scan (pool in the loop carry, read and written at a traced
+    layer index) and the unrolled loop (a python index into the same
+    stacked pool) serve byte-identical greedy tokens, through chunked
+    prefill and decode."""
+    from repro.kernels.paged_attention import ops
+    monkeypatch.setattr(ops, "_kernel_path",
+                        lambda window: path == "kernel"
+                        and isinstance(window, int))
+    cfg = reduced(get_config(name))
+    params = build(cfg).init(key)
+    prompts = [[int(t) for t in jax.random.randint(
+        jax.random.PRNGKey(7 + i), (5 + 3 * i,), 0, cfg.vocab_size)]
+        for i in range(3)]
+    tokens = []
+    for use_scan in (True, False):
+        eng = Engine(build(cfg.replace(use_scan=use_scan)), params,
+                     ServeConfig(max_seqs=2, block_size=4, max_len=32,
+                                 chunk_size=4, cache_dtype=cache_dtype))
+        rids = [eng.add_request(p, max_new_tokens=6) for p in prompts]
+        out, _ = eng.run()
+        tokens.append([out[r].tokens for r in rids])
+    assert tokens[0] == tokens[1]
