@@ -125,7 +125,7 @@ def kernel_parity(cfg, *, max_seqs: int, block_size: int, max_len: int,
 
     from repro.kernels.paged_attention import (
         paged_attention, paged_attention_reference, paged_prefill_attention,
-        paged_prefill_attention_reference, pool_dtype, quantize)
+        paged_prefill_attention_reference, pool_dtype, quantize, v_rows)
     from repro.models.layers import dtype_of
 
     B, H, KH = max_seqs, cfg.n_heads, cfg.n_kv_heads
@@ -139,7 +139,8 @@ def kernel_parity(cfg, *, max_seqs: int, block_size: int, max_len: int,
         return jnp.asarray(rng.standard_normal(shape, np.float32), dt)
 
     f32 = functools.partial(jnp.asarray, dtype=jnp.float32)
-    k, v = rand(P, KH, bs, D), rand(P, KH, bs, DV)
+    k, vh = rand(P, KH, bs, D), rand(P, KH, bs, DV)
+    v = v_rows(vh)
     tables = jnp.asarray(1 + rng.permutation(B * NB).reshape(B, NB),
                          jnp.int32)
     lens = jnp.asarray(rng.integers(1, NB * bs + 1, B), jnp.int32)
@@ -172,7 +173,8 @@ def kernel_parity(cfg, *, max_seqs: int, block_size: int, max_len: int,
                                                 starts, ends)
 
     qk, sk = quantize(f32(k), pool_dtype("int8"))
-    qv, sv = quantize(f32(v), pool_dtype("int8"))
+    qv, sv = quantize(f32(vh), pool_dtype("int8"))
+    qv = v_rows(qv)
     with jax.default_matmul_precision("highest"):
         ref = paged_attention_reference(f32(q), qk, qv, tables, lens,
                                         k_scale=sk, v_scale=sv)

@@ -17,7 +17,9 @@ it for visit counts is a bug.
 steps of it that touch a live block, from the lengths the call hands the
 kernel and with the kernel's own liveness predicate: the one place the
 grid's size is counted outside the kernel (the serving engine's
-``serve/*_attn_*_steps`` counters).
+``serve/*_attn_*_steps`` counters).  ``pool_lanes`` counts the lanes a
+pool leaf fills of those the TPU stores for it (the engine's
+``serve/v_pool_lanes_*`` counters).
 
 Sharded serving (DESIGN.md §10): when the serving engine traces with an
 active mesh (``distributed.sharding.use_rules(rules, mesh=mesh)``), the
@@ -45,7 +47,8 @@ from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import active_mesh, active_rules
 from repro.kernels.paged_attention.paged_attention import (
-    _block_live, paged_attention_kernel, paged_prefill_attention_kernel)
+    LANES, _block_live, paged_attention_kernel,
+    paged_prefill_attention_kernel)
 from repro.kernels.paged_attention.ref import (
     paged_attention_reference, paged_prefill_attention_reference)
 
@@ -73,6 +76,15 @@ def grid_steps(kv_lens, q_starts, num_table_blocks: int, block_size: int,
                        np.asarray(q_starts, np.int32)[:, None],
                        window=window, block_size=block_size)
     return int(np.count_nonzero(live)), kv.shape[0] * num_table_blocks
+
+
+def pool_lanes(shape) -> tuple[int, int]:
+    """(value, stored) lanes of a pool leaf of ``shape``: the lanes of its
+    minor dimension that hold values, and the lanes the TPU stores for
+    them (its tiles are ``LANES`` wide, so a minor dimension is padded up
+    to a multiple), each summed over the leaf's rows."""
+    rows, minor = math.prod(shape[:-1]), shape[-1]
+    return rows * minor, rows * -(-minor // LANES) * LANES
 
 
 def _serve_partition(B: int, H: int, KH: int):
@@ -111,7 +123,9 @@ def _kernel_call(entry, q, k_pool, v_pool, block_tables, rows, *, heads,
     """Run kernel ``entry`` on ``(q, k_pool, v_pool, block_tables, *rows)``
     (``rows``: the per-sequence (B,) operands), wrapped in ``shard_map``
     when a serving mesh splits the work.  Pools keep every leading axis
-    (layer, block) replicated; ``heads`` is q's head count."""
+    (layer, block) replicated; K and the scales split their KV-head axis,
+    V its lanes, which hold the heads side by side, so a shard's lanes
+    are its own heads' values; ``heads`` is q's head count."""
     fn = functools.partial(entry, interpret=not _on_tpu(),
                            return_visits=return_visits, **static)
     if layer is not None:
@@ -127,7 +141,7 @@ def _kernel_call(entry, q, k_pool, v_pool, block_tables, rows, *, heads,
     q_spec = P(bd, *(None,) * (q.ndim - 3), hd, None)
     kw_specs = {"layer": P(), "k_scale": P(*lead, hd, None),
                 "v_scale": P(*lead, hd, None)}
-    in_specs = (q_spec, P(*lead, hd, None, None), P(*lead, hd, None, None),
+    in_specs = (q_spec, P(*lead, hd, None, None), P(*lead, None, hd),
                 P(bd, None), *(P(bd),) * len(rows),
                 *(kw_specs[n] for n in kw))
     names = tuple(kw)
@@ -150,15 +164,16 @@ def paged_attention(q, k_pool, v_pool, block_tables, kv_lens, *,
                     use_kernel: bool | None = None,
                     return_visits: bool = False,
                     k_scale=None, v_scale=None):
-    """Decode: q (B, H, D); pools (L, P, KH, bs, D/DV) read at ``layer``
-    (the serving step's layer scan carries them whole), or one layer's
-    (P, KH, bs, D/DV) with no layer -> (B, H, DV).
+    """Decode: q (B, H, D); pools (L, P, KH, bs, D) and (L, P, bs, KH*DV)
+    read at ``layer`` (the serving step's layer scan carries them whole),
+    or one layer's (P, KH, bs, D) and (P, bs, KH*DV) with no layer -> (B,
+    H, DV).
 
     ``k_scale``/``v_scale`` ((L,) P, KH, bs) mark the pools as quantized:
     the kernel fuses dequantization into its score and value products;
     the reference dequantizes the gathered history.  Scale pools shard
-    exactly like their KV pools (kv_heads over the model axis) — same
-    placement, same shard_map specs minus the head_dim axis."""
+    like the K pool (kv_heads over the model axis) — same placement,
+    same shard_map specs minus the head_dim axis."""
     if use_kernel is None:
         use_kernel = _kernel_path(window)
     if use_kernel:

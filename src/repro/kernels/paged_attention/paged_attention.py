@@ -6,16 +6,27 @@ tile per sequence, and each query row r masks against its absolute
 position ``q_start + r // G`` — prefill-aware causal masking inside the
 online-softmax loop.
 
-Pool layout is head-major within a block: K/V pools are (L, P, KH, bs,
-D) and scale pools (L, P, KH, bs), every layer's pool stacked, and the
+K pools are head-major within a block, (L, P, KH, bs, D); V pools are
+token-major with the heads side by side on the lanes, (L, P, bs, KH*DV);
+scale pools are (L, P, KH, bs).  Every layer's pool is stacked, and the
 kernel reads the one at its ``layer`` index (a scalar-prefetch operand).
 The serving step carries the stacked pool through its layer scan and
 hands it here whole, so no layer's pool is ever sliced out and copied
 (DESIGN.md §8).  One block of one sequence is a single contiguous (KH,
-bs, D) DMA, each head's tile is a clean (bs, D) slab, and each head's
-scales are a (1, bs) lane row — the shapes whose trailing two dimensions
-equal the array's, which is what the TPU compiler accepts for
-BlockSpecs that are not (8, 128)-aligned; the layer axis is squeezed.
+bs, D) DMA of K and one (bs, KH*DV) DMA of V; each head's K tile is a
+clean (bs, D) slab, and each head's scales are a (1, bs) lane row — the
+shapes whose trailing two dimensions equal the array's, which is what
+the TPU compiler accepts for BlockSpecs that are not (8, 128)-aligned;
+the layer axis is squeezed.  V is token-major so that a value width
+narrower than the 128 lanes (a structurally pruned head, DV 64) still
+fills them: the TPU stores a leaf whose minor dimension is under 128
+lanes padded and in another layout than the kernel's row-major tiles,
+which would make every step program convert the whole pool.  The value
+product reads V in 128-lane groups at static, aligned lane offsets: a
+head of DV >= 128 owns whole groups, and heads narrower than that share
+a group, each keeping its own lanes of the group's product
+(``_lane_groups``).  The output is lane-dense the same way, (CG, KH*DV)
+per sequence.
 
 Grid (B, NB); the block dimension is innermost so the f32 online-softmax
 accumulators (acc, running max m, running sum l, one set per KV head)
@@ -49,6 +60,7 @@ and the online softmax stays f32 end-to-end.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -56,6 +68,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+LANES = 128                  # the TPU vector register's lane count
 
 
 def _block_live(j, kv_len, q_start, *, window: int, block_size: int):
@@ -68,6 +81,16 @@ def _block_live(j, kv_len, q_start, *, window: int, block_size: int):
     if window:
         live &= first + block_size - 1 > q_start - window
     return live
+
+
+def _lane_groups(kh: int, dv: int) -> tuple[int, int]:
+    """(heads per group, group width in lanes) of a V row of ``kh`` heads
+    of ``dv`` values: the value product reads one group at a time.  A
+    head of 128 or more values is its own group (aligned when ``dv`` is
+    a multiple of 128); narrower heads that divide the 128 lanes share
+    one, as many as divide ``kh``."""
+    per = math.gcd(kh, LANES // dv) if dv < LANES and LANES % dv == 0 else 1
+    return per, per * dv
 
 
 def _kernel(lens_ref, starts_ref, tables_ref, layer_ref, q_ref, k_ref,
@@ -83,6 +106,21 @@ def _kernel(lens_ref, starts_ref, tables_ref, layer_ref, q_ref, k_ref,
     j = pl.program_id(1)
     nb = pl.num_programs(1)
     kh = k_ref.shape[1]
+    dv = v_ref.shape[-1] // kh
+    per, width = _lane_groups(kh, dv)
+    groups = [range(g * per, (g + 1) * per) for g in range(kh // per)]
+
+    def by_lane(vals):
+        """One group's lanes from its heads' values ((CG, 1) or (CG,
+        width) each): lane i takes the value of the group's head i // dv."""
+        if per == 1:
+            return vals[0]
+        head = jax.lax.broadcasted_iota(
+            jnp.int32, (acc_ref.shape[0], width), 1) // dv
+        out = vals[-1]
+        for i in range(per - 1):
+            out = jnp.where(head == i, vals[i], out)
+        return out
 
     @pl.when(j == 0)
     def _init():
@@ -106,6 +144,7 @@ def _kernel(lens_ref, starts_ref, tables_ref, layer_ref, q_ref, k_ref,
         mask = (idx <= qpos) & (idx < kv_len)             # (CG, bs)
         if window:
             mask &= idx > qpos - window
+        probs, alphas = [], []
         for h in range(kh):
             q = q_ref[0, h].astype(jnp.float32)           # (CG, D)
             k = k_ref[0, h].astype(jnp.float32)           # (bs, D)
@@ -124,27 +163,39 @@ def _kernel(lens_ref, starts_ref, tables_ref, layer_ref, q_ref, k_ref,
             m_ref[h] = m_new
             if quantized:          # ... and each value's probability
                 p = p * vs_ref[0, pl.ds(h, 1), :]
-            v = v_ref[0, h].astype(jnp.float32)           # (bs, DV)
-            pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            acc_ref[h] = acc_ref[h] * alpha + pv
+            probs.append(p)
+            alphas.append(alpha)
+        for g, heads in enumerate(groups):
+            lanes = pl.ds(g * width, width)
+            v = v_ref[0, :, lanes].astype(jnp.float32)    # (bs, width)
+            # every head of the group multiplies the whole group; each
+            # keeps its own lanes of the product
+            pv = [jax.lax.dot_general(probs[h], v, (((1,), (0,)), ((), ())),
+                                      preferred_element_type=jnp.float32)
+                  for h in heads]                         # (CG, width) each
+            acc_ref[:, lanes] = (acc_ref[:, lanes]
+                                 * by_lane([alphas[h] for h in heads])
+                                 + by_lane(pv))
         cnt_ref[0] += 1
 
     @pl.when(j == nb - 1)
     def _done():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        for g, heads in enumerate(groups):
+            lanes = pl.ds(g * width, width)
+            l = by_lane([jnp.maximum(l_ref[h], 1e-30) for h in heads])
+            o_ref[0, :, lanes] = (acc_ref[:, lanes] / l).astype(o_ref.dtype)
         visits_ref[b] = cnt_ref[0]
 
 
 def _paged_attention(q, k_pool, v_pool, block_tables, q_starts, kv_lens, *,
                      window: int, scale: float | None, interpret: bool,
                      layer=None, k_scale=None, v_scale=None):
-    """q (B, C, H, D); pools (L, P, KH, bs, D/DV) read at ``layer`` (an
-    int32 scalar, traced or not), or one layer's (P, KH, bs, D/DV) when
-    ``layer`` is None; tables (B, NB); q_starts/kv_lens (B,);
-    k/v_scale (L, P, KH, bs) (or (P, KH, bs)) f32 when the pools are
-    quantized.  Returns (out (B, C, H, DV), visits (B, KH))."""
+    """q (B, C, H, D); k_pool (L, P, KH, bs, D) and v_pool (L, P, bs,
+    KH*DV) read at ``layer`` (an int32 scalar, traced or not), or one
+    layer's (P, KH, bs, D) and (P, bs, KH*DV) when ``layer`` is None;
+    tables (B, NB); q_starts/kv_lens (B,); k/v_scale (L, P, KH, bs) (or
+    (P, KH, bs)) f32 when the pools are quantized.  Returns (out (B, C,
+    H, DV), visits (B, KH))."""
     quantized = k_scale is not None
     if layer is None:
         k_pool, v_pool = k_pool[None], v_pool[None]
@@ -152,7 +203,8 @@ def _paged_attention(q, k_pool, v_pool, block_tables, q_starts, kv_lens, *,
             k_scale, v_scale = k_scale[None], v_scale[None]
         layer = 0
     B, C, H, D = q.shape
-    KH, bs, DV = k_pool.shape[2], k_pool.shape[3], v_pool.shape[4]
+    KH, bs, KDV = k_pool.shape[2], k_pool.shape[3], v_pool.shape[3]
+    DV = KDV // KH
     NB = block_tables.shape[1]
     G = H // KH
     CG = C * G
@@ -176,8 +228,8 @@ def _paged_attention(q, k_pool, v_pool, block_tables, q_starts, kv_lens, *,
         pl.BlockSpec((1, KH, CG, D), lambda b, *_: (b, 0, 0, 0)),
         pl.BlockSpec((None, 1, KH, bs, D),
                      lambda *a: (*_block(*a), 0, 0, 0)),
-        pl.BlockSpec((None, 1, KH, bs, DV),
-                     lambda *a: (*_block(*a), 0, 0, 0)),
+        pl.BlockSpec((None, 1, bs, KDV),
+                     lambda *a: (*_block(*a), 0, 0)),
     ]
     operands = [qg, k_pool, v_pool]
     if quantized:
@@ -193,11 +245,11 @@ def _paged_attention(q, k_pool, v_pool, block_tables, q_starts, kv_lens, *,
         grid=(B, NB),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, KH, CG, DV), lambda b, *_: (b, 0, 0, 0)),
+            pl.BlockSpec((1, CG, KDV), lambda b, *_: (b, 0, 0)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         scratch_shapes=[
-            pltpu.VMEM((KH, CG, DV), jnp.float32),
+            pltpu.VMEM((CG, KDV), jnp.float32),
             pltpu.VMEM((KH, CG, 1), jnp.float32),
             pltpu.VMEM((KH, CG, 1), jnp.float32),
             pltpu.SMEM((1,), jnp.int32),
@@ -206,14 +258,16 @@ def _paged_attention(q, k_pool, v_pool, block_tables, q_starts, kv_lens, *,
     out, visits = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, KH, CG, DV), q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((B, CG, KDV), q.dtype),
                    jax.ShapeDtypeStruct((B,), jnp.int32)],
         interpret=interpret,
         name="paged_attention",
     )(kv_lens.astype(jnp.int32), q_starts.astype(jnp.int32),
       block_tables.astype(jnp.int32),
       jnp.reshape(layer, (1,)).astype(jnp.int32), *operands)
-    out = out.reshape(B, KH, C, G, DV).transpose(0, 2, 1, 3, 4) \
+    # (B, C*G, KH*DV) -> (B, C, KH, G, DV): lanes of row r hold query
+    # (r // G, r % G) of every KV head
+    out = out.reshape(B, C, G, KH, DV).transpose(0, 1, 3, 2, 4) \
         .reshape(B, C, H, DV)
     # every KV head of a sequence visits the same blocks
     return out, jnp.broadcast_to(visits[:, None], (B, KH))

@@ -8,9 +8,13 @@ Shapes:
   q            (B, H, D)           one decode token per sequence,
                                    H = KH * G
   k_pool       (L, P, KH, bs, D)   every layer's block pool stacked (P
-  v_pool       (L, P, KH, bs, DV)  blocks of bs tokens, head-major within
+                                   blocks of bs tokens, head-major within
                                    a block), read at ``layer``; with no
-                                   layer, one layer's (P, KH, bs, D/DV)
+                                   layer, one layer's (P, KH, bs, D)
+  v_pool       (L, P, bs, KH*DV)   the same blocks token-major, the
+                                   heads side by side on the lanes
+                                   (``v_rows``); with no layer, one
+                                   layer's (P, bs, KH*DV)
   block_tables (B, NB) int32       pool index of each logical block
   kv_lens      (B,)    int32       valid tokens per sequence (incl.
                                    current)
@@ -20,8 +24,8 @@ Shapes:
                                    pools are quantized (int8 / fp8-e4m3)
 
 Output (B, H, DV).  The reference gathers ``pool[layer, block_tables]``,
-materializing that layer's history (B, NB*bs, KH, D) and nothing more;
-the Pallas kernel never materializes it.
+materializing that layer's history (B, NB*bs, KH, D/DV) and nothing
+more; the Pallas kernel never materializes it.
 """
 from __future__ import annotations
 
@@ -33,19 +37,30 @@ from repro.kernels.paged_attention.quant import dequantize
 NEG_INF = -1e30
 
 
-def _gather_kv(pool, block_tables, scale, layer):
+def v_rows(v):
+    """Head-major values (..., KH, bs, DV) in the V pool's token-major
+    layout (..., bs, KH*DV): row t holds token t of every head, head h
+    on lanes [h*DV, (h+1)*DV)."""
+    *lead, KH, bs, DV = v.shape
+    return jnp.swapaxes(v, -3, -2).reshape(*lead, bs, KH * DV)
+
+
+def _gather_kv(pool, block_tables, scale, layer, heads=None):
     """Gather (B, S, KH, d) history from ``layer``'s pool, dequantizing
     with the identically-gathered scales when given — the same
     bytes->values rule as the kernel's fused dequant, applied after
-    materialization."""
+    materialization.  ``heads`` marks a token-major V pool of that many
+    heads; without it the pool is head-major (K)."""
     B, NB = block_tables.shape
     at = block_tables if layer is None else (layer, block_tables)
-    out = pool[at]                                  # (B, NB, KH, bs, d)
-    KH, bs = out.shape[2], out.shape[3]
-    out = out.transpose(0, 1, 3, 2, 4).reshape(B, NB * bs, KH, -1)
+    out = pool[at]              # (B, NB, KH, bs, d) or (B, NB, bs, KH*d)
+    if heads is None:
+        out = out.transpose(0, 1, 3, 2, 4)
+    KH = out.shape[3] if heads is None else heads
+    out = out.reshape(B, NB * out.shape[2], KH, -1)
     if scale is not None:
         out = dequantize(out, scale[at].transpose(0, 1, 3, 2)
-                         .reshape(B, NB * bs, KH))
+                         .reshape(B, out.shape[1], KH))
     return out
 
 
@@ -67,7 +82,7 @@ def paged_prefill_attention_reference(q, k_pool, v_pool, block_tables,
     scale = scale if scale is not None else D ** -0.5
 
     k = _gather_kv(k_pool, block_tables, k_scale, layer)  # (B, S, KH, D)
-    v = _gather_kv(v_pool, block_tables, v_scale, layer)
+    v = _gather_kv(v_pool, block_tables, v_scale, layer, heads=KH)
 
     qg = q.reshape(B, C, KH, G, D)
     s = jnp.einsum("bqkgd,bskd->bkgqs", qg.astype(jnp.float32),
@@ -98,7 +113,7 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables, kv_lens, *,
     scale = scale if scale is not None else D ** -0.5
 
     k = _gather_kv(k_pool, block_tables, k_scale, layer)  # (B, S, KH, D)
-    v = _gather_kv(v_pool, block_tables, v_scale, layer)
+    v = _gather_kv(v_pool, block_tables, v_scale, layer, heads=KH)
 
     qg = q.reshape(B, KH, G, D)
     s = jnp.einsum("bkgd,bskd->bkgs", qg.astype(jnp.float32),

@@ -157,8 +157,8 @@ class Model:
         if cfg.family != "ssm":
             KH = cfg.n_kv_heads
             spec["k"] = sds((L, num_blocks, KH, block_size, cfg.head_dim_), dt)
-            spec["v"] = sds((L, num_blocks, KH, block_size, cfg.v_head_dim_),
-                            dt)
+            spec["v"] = sds((L, num_blocks, block_size,
+                             KH * cfg.v_head_dim_), dt)
             if quant:
                 for name in ("k_scale", "v_scale"):
                     spec[name] = sds((L, num_blocks, KH, block_size),

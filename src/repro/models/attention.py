@@ -166,16 +166,18 @@ def _scatter_kv(kv: dict, layer, k_new, v_new, block_tables, positions,
     map to through ``block_tables`` (B, NB).
 
     ``kv`` is the stacked pool that rides the layer scan's carry:
-    ``{"k", "v"}`` (L, P, KH, bs, hd/vhd) plus, when the pool is
-    quantized, ``{"k_scale", "v_scale"}`` (L, P, KH, bs) f32.  Each leaf
-    takes one scatter at (layer, block, head, offset) per token, which
-    XLA updates in place in the loop carry: no layer's pool is sliced out
-    or stacked back.  ``inchunk`` (B, C) bool masks padding: masked
-    tokens (and positions pointing past the table) are redirected to the
-    layer's reserved null block 0, where writes are harmless by
-    construction.  Shared by the paged decode, chunked-prefill and
-    speculative draft/verify paths, so the "where does a token's KV land
-    — and what bytes does it land as" rule exists exactly once.  Plain
+    ``{"k"}`` (L, P, KH, bs, hd) and ``{"v"}`` (L, P, bs, KH*vhd) plus,
+    when the pool is quantized, ``{"k_scale", "v_scale"}`` (L, P, KH, bs)
+    f32.  Each token takes one scatter per leaf — at (layer, block, head,
+    offset) into K and the scales, and one lane-dense row at (layer,
+    block, offset) into V — which XLA updates in place in the loop
+    carry: no layer's pool is sliced out or stacked back.  ``inchunk``
+    (B, C) bool masks padding: masked tokens (and positions pointing past
+    the table) are redirected to the layer's reserved null block 0, where
+    writes are harmless by construction.  Shared by the paged decode,
+    chunked-prefill and speculative draft/verify paths, so the "where
+    does a token's KV land — and what bytes does it land as" rule exists
+    exactly once.  Plain
     narrow pools cast on write (a draft pool may be allocated narrower
     than the compute dtype — ``ServeConfig.draft_cache_dtype``);
     quantized pools quantize symmetrically on write, storing the
@@ -189,18 +191,25 @@ def _scatter_kv(kv: dict, layer, k_new, v_new, block_tables, positions,
     if inchunk is not None:
         blk = jnp.where(inchunk, blk, 0)
         off = jnp.where(inchunk, off, 0)
-    # pools are head-major within a block: (layer, block, head, offset)
+    # K is head-major within a block: (layer, block, head, offset); V is
+    # token-major, one row of every head's values: (layer, block, offset)
     at = (layer, blk[..., None], jnp.arange(KH), off[..., None])
+    at_v = (layer, blk, off)
+    B, C = positions.shape
+
+    def rows(v):
+        return v.reshape(B, C, v_pool.shape[-1])
+
     if "k_scale" in kv:
         from repro.kernels.paged_attention import quantize
         qk, sk = quantize(k_new, k_pool.dtype)
         qv, sv = quantize(v_new, v_pool.dtype)
         return {"k": k_pool.at[at].set(qk),
-                "v": v_pool.at[at].set(qv),
+                "v": v_pool.at[at_v].set(rows(qv)),
                 "k_scale": kv["k_scale"].at[at].set(sk),
                 "v_scale": kv["v_scale"].at[at].set(sv)}
     return {"k": k_pool.at[at].set(k_new.astype(k_pool.dtype)),
-            "v": v_pool.at[at].set(v_new.astype(v_pool.dtype))}
+            "v": v_pool.at[at_v].set(rows(v_new).astype(v_pool.dtype))}
 
 
 def attention_paged_decode(params: dict, cfg, x: jax.Array,
@@ -212,13 +221,13 @@ def attention_paged_decode(params: dict, cfg, x: jax.Array,
     x (B,1,d); positions (B,) int32 — per-sequence write index (sequences in
     a serving batch are at *different* depths, unlike ``attention_decode``'s
     single scalar pos).  ``kv`` is the stacked pool ``{"k", "v"}``
-    (L, P, KH, bs, hd/vhd), plus ``{"k_scale", "v_scale"}`` when
-    quantized, carried whole through the layer scan; ``layer`` (int32
-    scalar, traced or not) is this layer's index into it: the new token
-    is scattered there and the attention reads it there.  block_tables
-    (B, NB) maps logical to pool blocks.  window: python int for static
-    masking (the kernel on TPU) or a (B,) array for per-sequence dynamic
-    windows (hybrid layers; reference path).
+    ((L, P, KH, bs, hd) and (L, P, bs, KH*vhd)), plus ``{"k_scale",
+    "v_scale"}`` when quantized, carried whole through the layer scan;
+    ``layer`` (int32 scalar, traced or not) is this layer's index into
+    it: the new token is scattered there and the attention reads it
+    there.  block_tables (B, NB) maps logical to pool blocks.  window:
+    python int for static masking (the kernel on TPU) or a (B,) array for
+    per-sequence dynamic windows (hybrid layers; reference path).
 
     Returns (out (B,1,d), new stacked kv dict).
     """

@@ -317,13 +317,15 @@ def paged_cache_axes(cfg: ArchConfig, quantized: bool = False) -> dict:
     *addressing*, not of parallelism; KV shards over kv_heads (tensor
     parallel) and the per-slot SSM state over the slot (``serve_batch``,
     data parallel) axis.  ``quantized`` adds the scale-pool leaves, which
-    shard *exactly* like their KV pools minus the head_dim axis: a
+    shard *exactly* like the K pool minus the head_dim axis: a
     tensor shard holding a kv-head's bytes holds its scales, and pure-DP
     per-device replicas carry scales alongside (DESIGN.md §10/§11)."""
     axes: dict[str, Any] = {}
     if cfg.family != "ssm":
         axes["k"] = ("layers", "serve_blocks", "kv_heads", None, None)
-        axes["v"] = ("layers", "serve_blocks", "kv_heads", None, None)
+        # V's lanes hold the heads side by side: sharding them over
+        # kv_heads gives each shard its own heads' contiguous lanes
+        axes["v"] = ("layers", "serve_blocks", None, "kv_heads")
         if quantized:
             axes["k_scale"] = ("layers", "serve_blocks", "kv_heads", None)
             axes["v_scale"] = ("layers", "serve_blocks", "kv_heads", None)
@@ -478,10 +480,13 @@ def init_paged_cache(cfg: ArchConfig, num_blocks: int, block_size: int,
     cache: dict[str, Any] = {}
     if cfg.family != "ssm":
         KH, hd, vhd = cfg.n_kv_heads, cfg.head_dim_, cfg.v_head_dim_
-        # head-major within a block: one block of one head is a
-        # contiguous (block_size, hd) tile for the paged-attention kernel
+        # K head-major within a block: one block of one head is a
+        # contiguous (block_size, hd) tile for the paged-attention kernel;
+        # V token-major, the heads side by side on the lanes, so a value
+        # width under the TPU's 128 lanes (a pruned head) still fills them
+        # and the pool keeps the kernel's row-major tiles (DESIGN.md §8)
         cache["k"] = jnp.zeros((L, num_blocks, KH, block_size, hd), dt)
-        cache["v"] = jnp.zeros((L, num_blocks, KH, block_size, vhd), dt)
+        cache["v"] = jnp.zeros((L, num_blocks, block_size, KH * vhd), dt)
         if quant:
             for name in ("k_scale", "v_scale"):
                 cache[name] = jnp.zeros((L, num_blocks, KH, block_size),

@@ -99,7 +99,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.distributed.sharding import tree_shardings, use_rules
 from repro.kernels.paged_attention import CACHE_DTYPES, is_quantized
-from repro.kernels.paged_attention.ops import grid_steps
+from repro.kernels.paged_attention.ops import grid_steps, pool_lanes
 from repro.obs import DEFAULT_TIME_BUCKETS, NULL_CTX, Telemetry, compile_log
 from repro.serve.faults import CrashError, FaultError, FaultInjector
 from repro.serve.kv_cache import OutOfBlocks, PagedCache
@@ -128,7 +128,11 @@ _RUN_COUNTERS = ("steps", "decode_tokens", "prefill_tokens",
                  "decode_attn_live_steps", "decode_attn_grid_steps",
                  "prefill_attn_live_steps", "prefill_attn_grid_steps",
                  # programs traced while the engine stepped (obs.CompileLog)
-                 "compiles")
+                 "compiles",
+                 # the V pool's lanes that hold values against those the
+                 # TPU stores for them, summed over the pool, once for
+                 # each step program that carries it (ops.pool_lanes)
+                 "v_pool_lanes_value", "v_pool_lanes_stored")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -429,6 +433,10 @@ class Engine:
                 self.cache)
             self.params = jax.device_put(params, self._params_sh)
             self.cache = jax.device_put(self.cache, self._cache_sh)
+        # what the V pool fills of the lanes stored for it, counted per
+        # step program that carries the pool (serve/v_pool_lanes_*)
+        self._v_lanes = (pool_lanes(self.cache["v"].shape)
+                         if "v" in self.cache else None)
         self._step_fn = self._make_fn(self._step_impl, "step", (1,))
         self._prefill_fn = self._make_fn(self._prefill_impl, "prefill", (1,))
         self._cow_fn = self._make_fn(self._cow_impl, "cow", (0,))
@@ -1888,6 +1896,7 @@ class Engine:
                                         tables.shape[1], self.cfg.block_size)
                 self._c["decode_attn_live_steps"].inc(live)
                 self._c["decode_attn_grid_steps"].inc(grid)
+            self._count_v_lanes()
             tok = jnp.asarray(tokens)
             for name, mask in feed.items():
                 if mask.any():
@@ -1899,6 +1908,11 @@ class Engine:
                 jnp.asarray(positions), jnp.asarray(tables),
                 jnp.asarray(temps), jnp.asarray(active), sub)
             fetch["dec"] = nxt
+
+    def _count_v_lanes(self) -> None:
+        if self._v_lanes is not None:
+            self._c["v_pool_lanes_value"].inc(self._v_lanes[0])
+            self._c["v_pool_lanes_stored"].inc(self._v_lanes[1])
 
     def _dispatch_prefill(self, plan, spec_k, fetch, sampled_prefills):
         """Every planned chunk rides ONE fixed-shape (max_seqs, C) call —
@@ -1928,6 +1942,7 @@ class Engine:
                                     ptables.shape[1], self.cfg.block_size)
             self._c["prefill_attn_live_steps"].inc(live)
             self._c["prefill_attn_grid_steps"].inc(grid)
+        self._count_v_lanes()
         args = (jnp.asarray(toks), jnp.asarray(pos),
                 jnp.asarray(np.arange(B, dtype=np.int32)),
                 jnp.asarray(ptables), jnp.asarray(valid))

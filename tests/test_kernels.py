@@ -101,12 +101,13 @@ def test_model_pallas_parity(key):
 @pytest.mark.parametrize("mode", ["decode", "prefill"])
 def test_paged_attention_reads_stacked_pool_at_layer(mode, cache_dtype,
                                                      layer):
-    """The kernel on every layer's pool stacked (L, P, KH, bs, d), handed
-    a traced layer index, equals the reference on that layer's pool
-    alone, and visits exactly the live grid steps ``grid_steps`` counts."""
+    """The kernel on every layer's pool stacked ((L, P, KH, bs, D) and
+    (L, P, bs, KH*DV)), handed a traced layer index, equals the reference
+    on that layer's pool alone, and visits exactly the live grid steps
+    ``grid_steps`` counts."""
     from repro.kernels.paged_attention import (
         paged_attention, paged_attention_reference, paged_prefill_attention,
-        paged_prefill_attention_reference, pool_dtype, quantize)
+        paged_prefill_attention_reference, pool_dtype, quantize, v_rows)
     from repro.kernels.paged_attention.ops import grid_steps
 
     L, B, C, H, KH, D, DV, bs, NB = 3, 3, 4, 4, 2, 16, 8, 4, 6
@@ -121,6 +122,7 @@ def test_paged_attention_reads_stacked_pool_at_layer(mode, cache_dtype,
         k, v, ks, vs = k.astype(jnp.bfloat16), v.astype(jnp.bfloat16), \
             None, None
         q_dtype, tol = jnp.bfloat16, 3e-2
+    v = v_rows(v)
     tables = jnp.asarray(1 + rng.permutation(B * NB).reshape(B, NB),
                          jnp.int32)
     lens = rng.integers(C, NB * bs + 1, size=(B,)).astype(np.int32)

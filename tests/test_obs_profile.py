@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config, reduced
+from repro.kernels.paged_attention import v_rows
 from repro.kernels.paged_attention.ops import grid_steps
 from repro.kernels.paged_attention.paged_attention import _paged_attention
 from repro.models import build
@@ -141,7 +142,7 @@ def test_grid_steps_equals_kernel_visits(pattern):
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
     q = jax.random.normal(ks[0], (B, C, H, D), jnp.float32)
     k_pool = jax.random.normal(ks[1], (P, KH, bs, D), jnp.float32)
-    v_pool = jax.random.normal(ks[2], (P, KH, bs, D), jnp.float32)
+    v_pool = v_rows(jax.random.normal(ks[2], (P, KH, bs, D), jnp.float32))
     tables = rng.integers(1, P, (B, NB)).astype(np.int32)
     _, visits = _paged_attention(
         q, k_pool, v_pool, jnp.asarray(tables),

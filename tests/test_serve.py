@@ -8,7 +8,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config, reduced
 from repro.kernels.paged_attention import (paged_attention,
-                                           paged_attention_reference)
+                                           paged_attention_reference, v_rows)
 from repro.launch.serve import generate
 from repro.models import build
 from repro.serve import (BlockAllocator, Engine, FCFSScheduler, OutOfBlocks,
@@ -139,7 +139,7 @@ def test_paged_attention_kernel_parity(B, H, KH, D, DV, bs, NB, window,
     P = B * NB + 1
     q = jnp.asarray(rng.normal(size=(B, H, D)), dtype)
     kp = jnp.asarray(rng.normal(size=(P, KH, bs, D)), dtype)
-    vp = jnp.asarray(rng.normal(size=(P, KH, bs, DV)), dtype)
+    vp = v_rows(jnp.asarray(rng.normal(size=(P, KH, bs, DV)), dtype))
     # non-contiguous tables: shuffled pool blocks, none uses block 0
     tables = jnp.asarray(
         1 + rng.permutation(B * NB).reshape(B, NB), jnp.int32)
@@ -161,7 +161,7 @@ def test_paged_attention_skips_fully_masked_blocks():
     P = B * NB + 1
     q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
     kp = jnp.asarray(rng.normal(size=(P, KH, bs, D)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, KH, bs, D)), jnp.float32)
+    vp = v_rows(jnp.asarray(rng.normal(size=(P, KH, bs, D)), jnp.float32))
     tables = jnp.asarray(1 + rng.permutation(B * NB).reshape(B, NB),
                          jnp.int32)
     lens = jnp.asarray([1, 5, 9], jnp.int32)          # 1-3 of 16 blocks live
@@ -182,7 +182,7 @@ def test_paged_attention_window_skips_left_of_window():
     P = B * NB + 1
     q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
     kp = jnp.asarray(rng.normal(size=(P, KH, bs, D)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, KH, bs, D)), jnp.float32)
+    vp = v_rows(jnp.asarray(rng.normal(size=(P, KH, bs, D)), jnp.float32))
     tables = jnp.asarray(1 + rng.permutation(NB)[None], jnp.int32)
     lens = jnp.asarray([NB * bs], jnp.int32)           # full table...
     out, visits = paged_attention(q, kp, vp, tables, lens, window=6,
@@ -206,7 +206,7 @@ def test_paged_prefill_kernel_parity(B, C, H, KH, D, bs, NB):
     P = B * NB + 1
     q = jnp.asarray(rng.normal(size=(B, C, H, D)), jnp.float32)
     kp = jnp.asarray(rng.normal(size=(P, KH, bs, D)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, KH, bs, D)), jnp.float32)
+    vp = v_rows(jnp.asarray(rng.normal(size=(P, KH, bs, D)), jnp.float32))
     tables = jnp.asarray(1 + rng.permutation(B * NB).reshape(B, NB),
                          jnp.int32)
     starts = jnp.asarray(rng.integers(0, NB * bs - C + 1, size=(B,)),
@@ -236,7 +236,7 @@ def test_paged_attention_matches_contiguous_flash():
         k, v, causal=True)[:, -1]
     # pools: per-sequence contiguous layout packed into one pool
     kp = k.reshape(B * NB, bs, KH, D).transpose(0, 2, 1, 3)
-    vp = v.reshape(B * NB, bs, KH, D).transpose(0, 2, 1, 3)
+    vp = v.reshape(B * NB, bs, KH * D)
     tables = jnp.arange(B * NB, dtype=jnp.int32).reshape(B, NB)
     lens = jnp.full((B,), S, jnp.int32)
     out = paged_attention(q1[:, 0], kp, vp, tables, lens, use_kernel=False)

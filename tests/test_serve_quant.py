@@ -27,7 +27,7 @@ from repro.configs import get_config, reduced
 from repro.kernels.paged_attention import (
     dequantize, is_quantized, paged_attention, paged_attention_reference,
     paged_prefill_attention, paged_prefill_attention_reference, pool_dtype,
-    quantize)
+    quantize, v_rows)
 from repro.kernels.paged_attention.paged_attention import _block_live
 from repro.launch.serve import generate
 from repro.models import build
@@ -46,7 +46,7 @@ def _quantized_pools(P, bs, KH, D, DV, dtype_name):
     dt = pool_dtype(dtype_name)
     qk, sk = quantize(k, dt)
     qv, sv = quantize(v, dt)
-    return k, v, qk, sk, qv, sv
+    return k, v_rows(v), qk, sk, v_rows(qv), sv
 
 
 # ---------------------------------------------------------------------------
@@ -215,7 +215,7 @@ def test_engine_scale_pools_allocated(key):
     assert eng.cache["v"].dtype == jnp.int8
     for name in ("k_scale", "v_scale"):
         assert eng.cache[name].dtype == jnp.float32
-        # scales mirror the pools' (L, P, KH, bs) block layout
+        # scales mirror the K pool's (L, P, KH, bs) block layout
         assert eng.cache[name].shape == eng.cache["k"].shape[:-1]
 
 

@@ -204,14 +204,14 @@ def test_kernel_shard_map_wrap_matches_unsharded():
     """The ops-level shard_map wrap itself: paged attention under an
     active serve mesh vs the plain kernel, decode + prefill entries."""
     from repro.distributed.sharding import use_rules
-    from repro.kernels.paged_attention import (paged_attention,
-                                               paged_prefill_attention)
+    from repro.kernels.paged_attention import (
+        paged_attention, paged_prefill_attention, v_rows)
 
     rng = np.random.default_rng(0)
     B, H, KH, D, bs, NB = 4, 4, 2, 8, 4, 3
     P = B * NB + 1
     kp = jnp.asarray(rng.normal(size=(P, KH, bs, D)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, KH, bs, D)), jnp.float32)
+    vp = v_rows(jnp.asarray(rng.normal(size=(P, KH, bs, D)), jnp.float32))
     tables = jnp.asarray(
         1 + np.arange(B * NB, dtype=np.int32).reshape(B, NB))
     lens = jnp.asarray([5, 9, 12, 7], jnp.int32)

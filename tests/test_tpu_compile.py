@@ -60,7 +60,7 @@ POOL_BLOCKS = SLOTS * NB + 1
 def _pool(kh, dv, dtype):
     """(k pool, v pool, tables, lens) shapes at the serving shape."""
     return ([((POOL_BLOCKS, kh, BLOCK, QWEN.head_dim_), dtype),
-             ((POOL_BLOCKS, kh, BLOCK, dv), dtype),
+             ((POOL_BLOCKS, BLOCK, kh * dv), dtype),
              ((SLOTS, NB), jnp.int32), ((SLOTS,), jnp.int32)])
 
 
@@ -168,7 +168,7 @@ def _lane_padded_bytes(leaf) -> int:
 @pytest.mark.parametrize("widths,cache_dtype,relaid", [
     (FULL, None, ()),
     (FULL, "int8", ("k_scale", "v_scale")),
-    (PRUNED, None, ("v",)),
+    (PRUNED, None, ()),
 ], ids=["full-bf16", "full-int8", "pruned-bf16"])
 def test_paged_steps_carry_pool_in_place(one_chip, monkeypatch, widths,
                                          cache_dtype, relaid):
@@ -177,11 +177,13 @@ def test_paged_steps_carry_pool_in_place(one_chip, monkeypatch, widths,
     or allocate a layer's slice of any pool leaf, nor slice, update or
     allocate a whole stacked leaf; the scan's only pool writes are
     in-place scatters.  Leaves whose minor dimension is narrower than the
-    128 lanes (``relaid``: the f32 scale pools' 16-token rows, the pruned
-    64-wide V pool) are stored by the TPU in another layout than the
-    kernel's row-major tiles, so the program converts them on entry and
-    exit: those whole-leaf copies, and their temporaries, are the only
-    pool-sized ones allowed."""
+    128 lanes (``relaid``: the f32 scale pools' 16-token rows) are stored
+    by the TPU in another layout than the kernel's row-major tiles, so
+    the program converts them on entry and exit: those whole-leaf copies,
+    and their temporaries, are the only pool-sized ones allowed.  The V
+    pool is token-major, its heads side by side on the lanes, so the
+    pruned model's 64-wide value heads fill 256 lanes and need no
+    conversion."""
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
     cfg = QWEN.replace(n_heads=widths["heads"], n_kv_heads=widths["kh"],
                        v_head_dim=widths["dv"], d_ff=widths["ff"])
