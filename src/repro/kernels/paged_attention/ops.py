@@ -8,18 +8,18 @@ take the reference, which takes window as an array and is the test
 oracle.  ``use_kernel=True`` forces the kernel, which then runs in
 interpret mode off the TPU — that is how tests exercise it on the CPU.
 
-``return_visits`` exposes the kernel's per-(seq, kv-head) block-visit
-counter (the fully-masked-block skip's observable); it is kernel-only —
+``return_visits`` exposes the kernel's per-(seq, kv-head) page-visit
+counter (the pages its live-page loop computed); it is kernel-only —
 the reference materializes every table entry by construction, so asking
 it for visit counts is a bug.
 
-``grid_steps`` counts, on the host, the grid a call launches and the
-steps of it that touch a live block, from the lengths the call hands the
-kernel and with the kernel's own liveness predicate: the one place the
-grid's size is counted outside the kernel (the serving engine's
-``serve/*_attn_*_steps`` counters).  ``pool_lanes`` counts the lanes a
-pool leaf fills of those the TPU stores for it (the engine's
-``serve/v_pool_lanes_*`` counters).
+``grid_steps`` counts, on the host, the pages a call computes and the
+page slots it launches (its loop iterations times the pages of a
+chunk), from the lengths the call hands the kernel and with the
+kernel's own ``live_pages``: the one place the kernel's work is counted
+outside it (the serving engine's ``serve/*_attn_*_steps`` counters).
+``pool_lanes`` counts the lanes a pool leaf fills of those the TPU
+stores for it (the engine's ``serve/v_pool_lanes_*`` counters).
 
 Sharded serving (DESIGN.md §10): when the serving engine traces with an
 active mesh (``distributed.sharding.use_rules(rules, mesh=mesh)``), the
@@ -47,7 +47,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import active_mesh, active_rules
 from repro.kernels.paged_attention.paged_attention import (
-    LANES, _block_live, paged_attention_kernel,
+    LANES, chunk_pages, live_pages, paged_attention_kernel,
     paged_prefill_attention_kernel)
 from repro.kernels.paged_attention.ref import (
     paged_attention_reference, paged_prefill_attention_reference)
@@ -63,19 +63,20 @@ def _kernel_path(window) -> bool:
     return _on_tpu() and isinstance(window, int)
 
 
-def grid_steps(kv_lens, q_starts, num_table_blocks: int, block_size: int,
+def grid_steps(kv_lens, q_starts, pages: int, block_size: int,
                window: int = 0) -> tuple[int, int]:
-    """(live, launched) grid steps of one kernel call over a batch with
+    """(live, launched) page slots of one kernel call over a batch with
     per-row ``kv_lens`` and ``q_starts`` (numpy, inactive rows included as
-    the kernel sees them) and a ``num_table_blocks``-wide block table.
-    The grid is (B, num_table_blocks); a step is live when
-    ``_block_live`` holds for it, and the kernel then DMAs and computes
-    its block (the per-row count is the kernel's ``visits``)."""
-    kv = np.asarray(kv_lens, np.int32)[:, None]
-    live = _block_live(np.arange(num_table_blocks, dtype=np.int32), kv,
-                       np.asarray(q_starts, np.int32)[:, None],
-                       window=window, block_size=block_size)
-    return int(np.count_nonzero(live)), kv.shape[0] * num_table_blocks
+    the kernel sees them), ``pages`` to a chunk (``chunk_pages`` of the
+    call's pools and table).  Each row loops over its ``live_pages`` a
+    chunk at a time: the live pages are what it computes (the per-row
+    count is the kernel's ``visits``), and each iteration launches a
+    chunk's worth of page slots, so the table's width costs nothing."""
+    first, end = live_pages(np.asarray(kv_lens, np.int32),
+                            np.asarray(q_starts, np.int32), window=window,
+                            block_size=block_size)
+    live = end - first
+    return int(live.sum()), int((-(-live // pages)).sum()) * pages
 
 
 def pool_lanes(shape) -> tuple[int, int]:

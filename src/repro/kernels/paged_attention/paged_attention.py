@@ -12,50 +12,57 @@ scale pools are (L, P, KH, bs).  Every layer's pool is stacked, and the
 kernel reads the one at its ``layer`` index (a scalar-prefetch operand).
 The serving step carries the stacked pool through its layer scan and
 hands it here whole, so no layer's pool is ever sliced out and copied
-(DESIGN.md §8).  One block of one sequence is a single contiguous (KH,
-bs, D) DMA of K and one (bs, KH*DV) DMA of V; each head's K tile is a
-clean (bs, D) slab, and each head's scales are a (1, bs) lane row — the
-shapes whose trailing two dimensions equal the array's, which is what
-the TPU compiler accepts for BlockSpecs that are not (8, 128)-aligned;
-the layer axis is squeezed.  V is token-major so that a value width
-narrower than the 128 lanes (a structurally pruned head, DV 64) still
-fills them: the TPU stores a leaf whose minor dimension is under 128
-lanes padded and in another layout than the kernel's row-major tiles,
-which would make every step program convert the whole pool.  The value
+(DESIGN.md §8).  One page of one sequence is a single contiguous (KH,
+bs, D) copy of K and one (bs, KH*DV) copy of V, each the whole of the
+pool's two minor dimensions, and each head's K tile is a clean (bs, D)
+slab.  V is token-major so that a value width narrower than the 128
+lanes (a structurally pruned head, DV 64) still fills them: the TPU
+stores a leaf whose minor dimension is under 128 lanes padded and in
+another layout than the kernel's row-major tiles, which would make
+every step program convert the whole pool.  The value
 product reads V in 128-lane groups at static, aligned lane offsets: a
 head of DV >= 128 owns whole groups, and heads narrower than that share
 a group, each keeping its own lanes of the group's product
 (``_lane_groups``).  The output is lane-dense the same way, (CG, KH*DV)
 per sequence.
 
-Grid (B, NB); the block dimension is innermost so the f32 online-softmax
-accumulators (acc, running max m, running sum l, one set per KV head)
-persist in VMEM scratch across the KV blocks of one sequence.  Every KV
-head is processed in the same grid step, so each pool block is read from
-HBM exactly once per sequence.  The block table, per-sequence lengths,
-query start positions and the layer index ride in as *scalar prefetch*
-operands (``pltpu.PrefetchScalarGridSpec``): the K/V BlockSpec index
-maps read ``layer`` and ``tables[b, j]`` to DMA the j-th logical block
-of sequence b from wherever it lives in that layer's pool — the gathered
-(B, S, KH, D) history is never materialized, which is the whole point of
-paging.
+Grid (B,): one step per sequence.  The step loops over the row's live
+pages only (``live_pages``: from the first page a query row can see up
+to the page of its last key), ``pages`` at a time (``chunk_pages``:
+enough that a chunk holds 128 keys, a full lane row of scores).  K and V
+stay in HBM (``pl.ANY``): each live page is one contiguous (KH, bs, D)
+DMA of K and one (bs, KH*DV) DMA of V from the stacked pools at
+``(layer, tables[b, j])`` into a VMEM chunk buffer, one DMA semaphore a
+page, and the buffer is double-buffered, so chunk c+1's copies run while
+chunk c is computed.  The gathered (B, S, KH, D) history is never
+materialized, which is the whole point of paging.  Each chunk is one
+online-softmax update over its pages * bs keys per KV head, with the f32
+accumulators (acc, running max m, running sum l, one set per KV head) in
+VMEM scratch; every KV head is processed in the same chunk, so each pool
+page crosses HBM once per sequence.  The block table, per-sequence
+lengths, query start positions and the layer index ride in as *scalar
+prefetch* operands (``pltpu.PrefetchScalarGridSpec``).
 
-Fully-masked blocks are skipped: table entries past a sequence's length
-(``j * block_size >= kv_len``) and, under a sliding window, blocks wholly
-left of every query's window are neither computed nor DMA'd — their
-BlockSpec index degrades to the null block 0 in both cases, so a
-window-dead block costs neither FLOPs nor HBM bandwidth.  A per-sequence
-visit counter is emitted alongside the output so tests can assert the
-skip actually fires (tests/test_serve.py): the counter and the index map
-share one liveness predicate (``_block_live``), so "was computed" and
-"was DMA'd" cannot drift apart.
+Pages outside the live range are neither fetched nor computed: a row
+with no live page (an idle prefill row, ``kv_len`` 0) runs no iteration
+and writes zeros, and the page slots of a row's last chunk past its last
+page copy nothing and are masked, like every position past ``kv_len``,
+ahead of a query or left of its window.  The cost of a call therefore
+follows the live KV, not the block table's width.  A per-sequence visit
+counter (the pages the loop computed) is emitted alongside the output,
+and ``ops.grid_steps`` counts the same pages and the page slots launched
+on the host with the same ``live_pages``, so "was computed" and "was
+counted" cannot drift apart.
 
-Quantized pools (DESIGN.md §11): when the pool stores int8/fp8-e4m3, the
-f32 scale pools ride in as two extra operands addressed by the *same*
-index map as K/V, and dequantization is fused into the score and value
-products: a key's scale multiplies its score column and a value's scale
-multiplies its probability column, so only the narrow bytes cross HBM
-and the online softmax stays f32 end-to-end.
+Quantized pools (DESIGN.md §11): when the pool stores int8/fp8-e4m3,
+dequantization is fused into the score and value products: a key's f32
+scale multiplies its score column and a value's its probability column,
+so only the narrow bytes of K and V cross HBM and the online softmax
+stays f32 end-to-end.  The scale pools' minor dimension is the block's
+tokens, under the 128 lanes, and the TPU compiler refuses a copy of
+part of such a leaf; so the call gathers the scales of each row's chunks
+lane-dense, (B, chunks, KH, pages*bs), with the same ``live_pages``, and
+each row's scales ride in as one block beside its queries.
 """
 from __future__ import annotations
 
@@ -64,23 +71,43 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LANES = 128                  # the TPU vector register's lane count
+CHUNK_KEYS = 128             # keys a chunk holds at least: a lane row
+CHUNK_VMEM = 4 << 20         # bytes of VMEM for the double-buffered K/V chunk
 
 
-def _block_live(j, kv_len, q_start, *, window: int, block_size: int):
-    """One liveness predicate for compute AND DMA: a block is dead when
-    every one of its positions is masked for every query row — past the
-    sequence's length, or (sliding window) wholly left of even the
-    oldest query's window."""
-    first = j * block_size
-    live = first < kv_len
-    if window:
-        live &= first + block_size - 1 > q_start - window
-    return live
+def live_pages(kv_len, q_start, *, window: int, block_size: int):
+    """(first, end): the pages [first, end) of a row that hold a position
+    some query row sees — up to the page of the row's last key and, under
+    a sliding window, from the first page not wholly left of the oldest
+    query's window.  ``end >= first``.  One definition for the kernel's
+    loop (traced scalars), the call's scale gather and the host's count
+    (numpy arrays)."""
+    xp = jnp if isinstance(kv_len, jax.Array) else np
+    end = (kv_len + block_size - 1) // block_size
+    if not window:
+        return end * 0, end
+    first = xp.maximum(q_start - window + 1, 0) // block_size
+    return first, xp.maximum(end, first)
+
+
+def chunk_pages(k_pool, v_pool, table_width: int) -> int:
+    """Pages one loop iteration fetches and computes: enough that a chunk
+    holds ``CHUNK_KEYS`` keys, no more than the block table holds, and
+    few enough that the double-buffered K and V chunk fits in
+    ``CHUNK_VMEM`` bytes beside the prefill tile.  It reads the pools'
+    shapes and dtypes only, so the host can count the kernel's iterations
+    from the pools it built."""
+    bs = k_pool.shape[-2]
+    token = (k_pool.shape[-3] * k_pool.shape[-1] * k_pool.dtype.itemsize
+             + v_pool.shape[-1] * v_pool.dtype.itemsize)
+    return max(1, min(-(-CHUNK_KEYS // bs), CHUNK_VMEM // (2 * bs * token),
+                      table_width))
 
 
 def _lane_groups(kh: int, dv: int) -> tuple[int, int]:
@@ -93,81 +120,96 @@ def _lane_groups(kh: int, dv: int) -> tuple[int, int]:
     return per, per * dv
 
 
-def _kernel(lens_ref, starts_ref, tables_ref, layer_ref, q_ref, k_ref,
-            v_ref, *refs, scale: float, window: int, block_size: int,
-            group: int, quantized: bool):
-    del layer_ref                 # addresses the pools in the index maps
+def _kernel(lens_ref, starts_ref, tables_ref, layer_ref, q_ref, k_hbm,
+            v_hbm, *refs, scale: float, window: int, block_size: int,
+            group: int, pages: int, quantized: bool):
     if quantized:
-        ks_ref, vs_ref, o_ref, visits_ref, acc_ref, m_ref, l_ref, \
-            cnt_ref = refs
-    else:
-        o_ref, visits_ref, acc_ref, m_ref, l_ref, cnt_ref = refs
+        ks_ref, vs_ref, *refs = refs
+    o_ref, visits_ref, k_buf, v_buf, sems, acc_ref, m_ref, l_ref = refs
     b = pl.program_id(0)
-    j = pl.program_id(1)
-    nb = pl.num_programs(1)
-    kh = k_ref.shape[1]
-    dv = v_ref.shape[-1] // kh
+    kh = k_buf.shape[2]
+    dv = v_buf.shape[-1] // kh
     per, width = _lane_groups(kh, dv)
     groups = [range(g * per, (g + 1) * per) for g in range(kh // per)]
+    cg = q_ref.shape[2]
+    keys = pages * block_size
+    nb = tables_ref.shape[1]
+    kv_len, q_start, layer = lens_ref[b], starts_ref[b], layer_ref[0]
+    first, end = live_pages(kv_len, q_start, window=window,
+                            block_size=block_size)
+    chunks = (end - first + pages - 1) // pages
 
     def by_lane(vals):
         """One group's lanes from its heads' values ((CG, 1) or (CG,
         width) each): lane i takes the value of the group's head i // dv."""
         if per == 1:
             return vals[0]
-        head = jax.lax.broadcasted_iota(
-            jnp.int32, (acc_ref.shape[0], width), 1) // dv
+        head = jax.lax.broadcasted_iota(jnp.int32, (cg, width), 1) // dv
         out = vals[-1]
         for i in range(per - 1):
             out = jnp.where(head == i, vals[i], out)
         return out
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        cnt_ref[0] = 0
+    def fetch(c, slot, wait: bool):
+        """Start (or wait for) the K and V copies of chunk c's live pages
+        into buffer ``slot``; slots past the row's last page copy nothing."""
+        for i in range(pages):
+            j = first + c * pages + i
+            page = tables_ref[b, jnp.minimum(j, nb - 1)]
+            copies = [pltpu.make_async_copy(k_hbm.at[layer, page],
+                                            k_buf.at[slot, i],
+                                            sems.at[0, slot, i]),
+                      pltpu.make_async_copy(v_hbm.at[layer, page],
+                                            v_buf.at[slot, i],
+                                            sems.at[1, slot, i])]
 
-    kv_len = lens_ref[b]
-    q_start = starts_ref[b]
-    first = j * block_size
-    visited = _block_live(j, kv_len, q_start, window=window,
-                          block_size=block_size)
+            @pl.when(j < end)
+            def _():
+                for cp in copies:
+                    if wait:
+                        cp.wait()
+                    else:
+                        cp.start()
 
-    @pl.when(visited)
-    def _update():
-        cg = q_ref.shape[2]
-        idx = first + jax.lax.broadcasted_iota(jnp.int32, (cg, block_size), 1)
+    def update(c, slot):
+        """One online-softmax step over chunk c's keys, every KV head."""
+        idx = (first + c * pages) * block_size + \
+            jax.lax.broadcasted_iota(jnp.int32, (cg, keys), 1)
         qpos = q_start + jax.lax.broadcasted_iota(
-            jnp.int32, (cg, block_size), 0) // group
-        mask = (idx <= qpos) & (idx < kv_len)             # (CG, bs)
+            jnp.int32, (cg, keys), 0) // group
+        mask = (idx <= qpos) & (idx < kv_len)             # (CG, keys)
         if window:
             mask &= idx > qpos - window
         probs, alphas = [], []
         for h in range(kh):
             q = q_ref[0, h].astype(jnp.float32)           # (CG, D)
-            k = k_ref[0, h].astype(jnp.float32)           # (bs, D)
+            k = k_buf[slot, :, h].astype(jnp.float32)     # (pages, bs, D)
             s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
+                q, k.reshape(keys, k.shape[-1]), (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
             if quantized:          # fused dequant: scale each key's column
-                s = s * ks_ref[0, pl.ds(h, 1), :]         # (1, bs)
+                s = s * ks_ref[0, c, pl.ds(h, 1), :]      # (1, keys)
             s = jnp.where(mask, s, NEG_INF)
 
             m_prev = m_ref[h]                             # (CG, 1)
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)                        # (CG, bs)
+            p = jnp.exp(s - m_new)                        # (CG, keys)
             l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
             m_ref[h] = m_new
             if quantized:          # ... and each value's probability
-                p = p * vs_ref[0, pl.ds(h, 1), :]
+                p = p * vs_ref[0, c, pl.ds(h, 1), :]
             probs.append(p)
             alphas.append(alpha)
+        # a masked key still multiplies its value by a zero probability:
+        # the slots past a row's last page, which copy nothing, must read
+        # as zeros and not as whatever the buffer held
+        real = (first + c * pages) * block_size + jax.lax.broadcasted_iota(
+            jnp.int32, (keys, width), 0) < kv_len
         for g, heads in enumerate(groups):
             lanes = pl.ds(g * width, width)
-            v = v_ref[0, :, lanes].astype(jnp.float32)    # (bs, width)
+            v = v_buf[slot, :, :, lanes].astype(jnp.float32)
+            v = jnp.where(real, v.reshape(keys, width), 0.0)  # (keys, width)
             # every head of the group multiplies the whole group; each
             # keeps its own lanes of the product
             pv = [jax.lax.dot_general(probs[h], v, (((1,), (0,)), ((), ())),
@@ -176,15 +218,48 @@ def _kernel(lens_ref, starts_ref, tables_ref, layer_ref, q_ref, k_ref,
             acc_ref[:, lanes] = (acc_ref[:, lanes]
                                  * by_lane([alphas[h] for h in heads])
                                  + by_lane(pv))
-        cnt_ref[0] += 1
 
-    @pl.when(j == nb - 1)
-    def _done():
-        for g, heads in enumerate(groups):
-            lanes = pl.ds(g * width, width)
-            l = by_lane([jnp.maximum(l_ref[h], 1e-30) for h in heads])
-            o_ref[0, :, lanes] = (acc_ref[:, lanes] / l).astype(o_ref.dtype)
-        visits_ref[b] = cnt_ref[0]
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(chunks > 0)
+    def _first():
+        fetch(0, 0, wait=False)
+
+    def step(c, seen):
+        slot = c % 2
+
+        @pl.when(c + 1 < chunks)
+        def _next():
+            fetch(c + 1, 1 - slot, wait=False)
+
+        fetch(c, slot, wait=True)
+        update(c, slot)
+        return seen + jnp.minimum(pages, end - first - c * pages)
+
+    visits_ref[b] = jax.lax.fori_loop(0, chunks, step, jnp.int32(0))
+    for g, heads in enumerate(groups):
+        lanes = pl.ds(g * width, width)
+        l = by_lane([jnp.maximum(l_ref[h], 1e-30) for h in heads])
+        o_ref[0, :, lanes] = (acc_ref[:, lanes] / l).astype(o_ref.dtype)
+
+
+def _chunk_scales(scales, layer, block_tables, q_starts, kv_lens, *,
+                  window: int, pages: int):
+    """The scales of each row's chunks, lane-dense: (B, chunks, KH,
+    pages*bs), chunk c of row b holding pages ``first + c*pages + i`` of
+    its table (block 0 past its last page) side by side on the lanes."""
+    B, NB = block_tables.shape
+    KH, bs = scales.shape[-2:]
+    chunks = -(-NB // pages)
+    first, end = live_pages(kv_lens, q_starts, window=window, block_size=bs)
+    j = first[:, None] + jnp.arange(chunks * pages, dtype=jnp.int32)
+    idx = jnp.where(j < end[:, None], jnp.take_along_axis(
+        block_tables, jnp.minimum(j, NB - 1), axis=1), 0)
+    s = scales[layer, idx]               # (B, chunks * pages, KH, bs)
+    return s.reshape(B, chunks, pages, KH, bs).transpose(0, 1, 3, 2, 4) \
+        .reshape(B, chunks, KH, pages * bs)
 
 
 def _paged_attention(q, k_pool, v_pool, block_tables, q_starts, kv_lens, *,
@@ -209,50 +284,47 @@ def _paged_attention(q, k_pool, v_pool, block_tables, q_starts, kv_lens, *,
     G = H // KH
     CG = C * G
     scale = scale if scale is not None else D ** -0.5
+    pages = chunk_pages(k_pool, v_pool, NB)
+    layer = jnp.reshape(layer, (1,)).astype(jnp.int32)
+    tables = block_tables.astype(jnp.int32)
+    q_starts = q_starts.astype(jnp.int32)
+    kv_lens = kv_lens.astype(jnp.int32)
 
     # (B, C, KH, G, D) -> (B, KH, C*G, D): row r is query (r // G, r % G)
     qg = q.reshape(B, C, KH, G, D).transpose(0, 2, 1, 3, 4) \
         .reshape(B, KH, CG, D)
     kernel = functools.partial(_kernel, scale=scale, window=window,
-                               block_size=bs, group=G, quantized=quantized)
-
-    def _block(b, j, lens, starts, tables, layer_idx):
-        # skip the DMA for fully-masked blocks — past the sequence's
-        # length, or wholly left of the sliding window: read null block 0
-        # of the same layer
-        live = _block_live(j, lens[b], starts[b], window=window,
-                           block_size=bs)
-        return layer_idx[0], jnp.where(live, tables[b, j], 0)
-
+                               block_size=bs, group=G, pages=pages,
+                               quantized=quantized)
     in_specs = [
         pl.BlockSpec((1, KH, CG, D), lambda b, *_: (b, 0, 0, 0)),
-        pl.BlockSpec((None, 1, KH, bs, D),
-                     lambda *a: (*_block(*a), 0, 0, 0)),
-        pl.BlockSpec((None, 1, bs, KDV),
-                     lambda *a: (*_block(*a), 0, 0)),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
     ]
     operands = [qg, k_pool, v_pool]
     if quantized:
-        # scales ride as two extra operands addressed by the same index
-        # map as their pools, so a skipped KV DMA skips its scales too
-        in_specs += [pl.BlockSpec((None, 1, KH, bs),
-                                  lambda *a: (*_block(*a), 0, 0))] * 2
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
+        rows = [_chunk_scales(s.astype(jnp.float32), layer[0], tables,
+                              q_starts, kv_lens, window=window, pages=pages)
+                for s in (k_scale, v_scale)]
+        in_specs += [pl.BlockSpec((1, *rows[0].shape[1:]),
+                                  lambda b, *_: (b, 0, 0, 0))] * 2
+        operands += rows
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(B, NB),
+        grid=(B,),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, CG, KDV), lambda b, *_: (b, 0, 0)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         scratch_shapes=[
+            pltpu.VMEM((2, pages, KH, bs, D), k_pool.dtype),
+            pltpu.VMEM((2, pages, bs, KDV), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2, pages)),
             pltpu.VMEM((CG, KDV), jnp.float32),
             pltpu.VMEM((KH, CG, 1), jnp.float32),
             pltpu.VMEM((KH, CG, 1), jnp.float32),
-            pltpu.SMEM((1,), jnp.int32),
         ],
     )
     out, visits = pl.pallas_call(
@@ -262,14 +334,12 @@ def _paged_attention(q, k_pool, v_pool, block_tables, q_starts, kv_lens, *,
                    jax.ShapeDtypeStruct((B,), jnp.int32)],
         interpret=interpret,
         name="paged_attention",
-    )(kv_lens.astype(jnp.int32), q_starts.astype(jnp.int32),
-      block_tables.astype(jnp.int32),
-      jnp.reshape(layer, (1,)).astype(jnp.int32), *operands)
+    )(kv_lens, q_starts, tables, layer, *operands)
     # (B, C*G, KH*DV) -> (B, C, KH, G, DV): lanes of row r hold query
     # (r // G, r % G) of every KV head
     out = out.reshape(B, C, G, KH, DV).transpose(0, 1, 3, 2, 4) \
         .reshape(B, C, H, DV)
-    # every KV head of a sequence visits the same blocks
+    # every KV head of a sequence visits the same pages
     return out, jnp.broadcast_to(visits[:, None], (B, KH))
 
 

@@ -99,7 +99,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.distributed.sharding import tree_shardings, use_rules
 from repro.kernels.paged_attention import CACHE_DTYPES, is_quantized
-from repro.kernels.paged_attention.ops import grid_steps, pool_lanes
+from repro.kernels.paged_attention.ops import (chunk_pages, grid_steps,
+                                              pool_lanes)
 from repro.obs import DEFAULT_TIME_BUCKETS, NULL_CTX, Telemetry, compile_log
 from repro.serve.faults import CrashError, FaultError, FaultInjector
 from repro.serve.kv_cache import OutOfBlocks, PagedCache
@@ -123,7 +124,7 @@ _RUN_COUNTERS = ("steps", "decode_tokens", "prefill_tokens",
                  # what the step programs carry (counted at dispatch from
                  # the host arrays): real prefill tokens against the
                  # call's token slots, and the paged-attention kernel's
-                 # live against launched grid steps (ops.grid_steps)
+                 # live pages against launched page slots (ops.grid_steps)
                  "prefill_chunk_tokens", "prefill_token_slots",
                  "decode_attn_live_steps", "decode_attn_grid_steps",
                  "prefill_attn_live_steps", "prefill_attn_grid_steps",
@@ -450,6 +451,9 @@ class Engine:
         # (0 in the paged steps); hybrid layers' dynamic window takes the
         # reference, and ssm has no attention: no grid to count
         self._attn_grid = model.cfg.family != "ssm" and not model.cfg.hybrid
+        if self._attn_grid:        # the pages a kernel iteration covers
+            self._chunk_pages = chunk_pages(self.cache["k"], self.cache["v"],
+                                            self.cfg.blocks_per_seq)
         # speculative decoding capability gate: rejected drafts roll back
         # by dropping KV cursor positions; recurrent SSM/conv state has no
         # such rewind, so SSM/hybrid fall back to dense-only decode
@@ -1724,6 +1728,9 @@ class Engine:
             else:
                 self.scheduler.waiting.remove(src)
             self._forget_rid(rid)
+            # the last request moved away leaves this engine idle, and it
+            # may never step again to hand injected holds back
+            self._idle_release_holds()
         return h
 
     def export_backlog(self, remove: bool = False) -> list[SequenceHandoff]:
@@ -1893,7 +1900,8 @@ class Engine:
         else:
             if self._attn_grid:
                 live, grid = grid_steps(positions + 1, positions,
-                                        tables.shape[1], self.cfg.block_size)
+                                        self._chunk_pages,
+                                        self.cfg.block_size)
                 self._c["decode_attn_live_steps"].inc(live)
                 self._c["decode_attn_grid_steps"].inc(grid)
             self._count_v_lanes()
@@ -1939,7 +1947,7 @@ class Engine:
         self._c["prefill_token_slots"].inc(B * C)
         if self._attn_grid:
             live, grid = grid_steps(pos[:, 0] + valid, pos[:, 0],
-                                    ptables.shape[1], self.cfg.block_size)
+                                    self._chunk_pages, self.cfg.block_size)
             self._c["prefill_attn_live_steps"].inc(live)
             self._c["prefill_attn_grid_steps"].inc(grid)
         self._count_v_lanes()

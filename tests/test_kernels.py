@@ -97,48 +97,59 @@ def test_model_pallas_parity(key):
 
 
 @pytest.mark.parametrize("layer", ["first", "last"])
-@pytest.mark.parametrize("cache_dtype", ["bfloat16", "int8"])
+@pytest.mark.parametrize("cache_dtype", ["bfloat16", "int8", "fp8_e4m3"])
 @pytest.mark.parametrize("mode", ["decode", "prefill"])
 def test_paged_attention_reads_stacked_pool_at_layer(mode, cache_dtype,
                                                      layer):
     """The kernel on every layer's pool stacked ((L, P, KH, bs, D) and
-    (L, P, bs, KH*DV)), handed a traced layer index, equals the reference
-    on that layer's pool alone, and visits exactly the live grid steps
+    (L, P, bs, KH*DV), two 8-wide value heads sharing a lane group),
+    handed a traced layer index, equals the reference on that layer's
+    pool alone at the edges of its chunks (32 pages of 4 tokens, 128
+    keys): kv lengths 1, one block, a chunk less one, a chunk, a chunk
+    and one, the whole table.  A row with kv length 0 runs no iteration
+    and writes zeros.  The kernel visits exactly the live pages
     ``grid_steps`` counts."""
     from repro.kernels.paged_attention import (
         paged_attention, paged_attention_reference, paged_prefill_attention,
         paged_prefill_attention_reference, pool_dtype, quantize, v_rows)
-    from repro.kernels.paged_attention.ops import grid_steps
+    from repro.kernels.paged_attention.ops import chunk_pages, grid_steps
 
-    L, B, C, H, KH, D, DV, bs, NB = 3, 3, 4, 4, 2, 16, 8, 4, 6
+    L, C, H, KH, D, DV, bs, NB = 3, 4, 4, 2, 16, 8, 4, 40
+    keys = 128                         # a chunk: 32 pages of 4 tokens
+    lens = np.array([1, bs, keys - 1, keys, keys + 1, NB * bs, 0], np.int32)
+    B = len(lens)
     P = B * NB + 1
     at = 0 if layer == "first" else L - 1
     k = jnp.asarray(rng.normal(size=(L, P, KH, bs, D)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(L, P, KH, bs, DV)), jnp.float32)
-    if cache_dtype == "int8":
-        (k, ks), (v, vs) = (quantize(x, pool_dtype("int8")) for x in (k, v))
+    if cache_dtype != "bfloat16":
+        (k, ks), (v, vs) = (quantize(x, pool_dtype(cache_dtype))
+                            for x in (k, v))
         q_dtype, tol = jnp.float32, 1e-5
     else:
         k, v, ks, vs = k.astype(jnp.bfloat16), v.astype(jnp.bfloat16), \
             None, None
         q_dtype, tol = jnp.bfloat16, 3e-2
     v = v_rows(v)
+    assert chunk_pages(k, v, NB) * bs == keys
     tables = jnp.asarray(1 + rng.permutation(B * NB).reshape(B, NB),
                          jnp.int32)
-    lens = rng.integers(C, NB * bs + 1, size=(B,)).astype(np.int32)
     one = dict(k_scale=None if ks is None else ks[at],
                v_scale=None if vs is None else vs[at])
     if mode == "decode":
         q = jnp.asarray(rng.normal(size=(B, H, D)), q_dtype)
         starts = lens - 1
+        valid = np.minimum(lens, 1)
         out, visits = jax.jit(lambda q, layer: paged_attention(
             q, k, v, tables, jnp.asarray(lens), layer=layer, k_scale=ks,
             v_scale=vs, use_kernel=True, return_visits=True))(q, at)
         ref = paged_attention_reference(q, k[at], v[at], tables,
                                         jnp.asarray(lens), **one)
+        out, ref = out[:, None], ref[:, None]
     else:
         q = jnp.asarray(rng.normal(size=(B, C, H, D)), q_dtype)
-        starts = lens - C
+        starts = np.maximum(lens - C, 0)
+        valid = lens - starts
         out, visits = jax.jit(lambda q, layer: paged_prefill_attention(
             q, k, v, tables, jnp.asarray(starts), jnp.asarray(lens),
             layer=layer, k_scale=ks, v_scale=vs, use_kernel=True,
@@ -146,8 +157,11 @@ def test_paged_attention_reads_stacked_pool_at_layer(mode, cache_dtype,
         ref = paged_prefill_attention_reference(
             q, k[at], v[at], tables, jnp.asarray(starts), jnp.asarray(lens),
             **one)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32),
-                               rtol=tol, atol=tol)
-    live, _ = grid_steps(lens, starts, NB, bs)
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    for b in range(B):                 # rows past valid are don't-care
+        np.testing.assert_allclose(out[b, :valid[b]], ref[b, :valid[b]],
+                                   rtol=tol, atol=tol)
+    assert not out[-1].any()           # the empty row: zeros, no NaN
+    live, _ = grid_steps(lens, starts, chunk_pages(k, v, NB), bs)
+    assert int(np.asarray(visits)[-1, 0]) == 0
     assert int(np.asarray(visits)[:, 0].sum()) == live

@@ -1,7 +1,7 @@
 """The engine's measurements where the work happens (DESIGN.md §12): step
 phases as ``serve.*`` annotations on the profiler's clock, the dispatch
 counters of what the step programs carry (real prefill tokens against
-token slots; live against launched grid steps of the paged-attention
+token slots; live against launched page slots of the paged-attention
 kernel), and the count of programs traced while the engine stepped."""
 import glob
 import os
@@ -14,7 +14,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config, reduced
 from repro.kernels.paged_attention import v_rows
-from repro.kernels.paged_attention.ops import grid_steps
+from repro.kernels.paged_attention.ops import chunk_pages, grid_steps
 from repro.kernels.paged_attention.paged_attention import _paged_attention
 from repro.models import build
 from repro.obs import Telemetry
@@ -106,14 +106,19 @@ def test_profiler_trace_holds_step_phases(tiny, tmp_path, enabled):
 
 def test_dispatch_counters_match_hand_counts(tiny):
     """One 10-token prompt, chunks of 4 tokens, blocks of 4, 2 slots and
-    8-block tables (max_len 32), 3 output tokens, telemetry off:
+    8-block tables (max_len 32), 3 output tokens, telemetry off.  The
+    kernel's chunk is 8 pages (128 keys would be 32 blocks of 4; the
+    8-block table caps it), so each row with a live page launches one
+    iteration of 8 page slots:
 
     - prefill: three calls carry 4, 4 and 2 real tokens in 2 x 4 slots
-      each; the live row's kv lengths 4, 8, 10 touch 1, 2, 3 blocks, the
-      idle row (length 0) none; each call launches 2 x 8 grid steps;
+      each; the live row's kv lengths 4, 8, 10 touch 1, 2, 3 blocks, one
+      iteration each; the idle row (length 0) runs none: 3 x 8 slots;
     - decode: two calls at positions 10 and 11 (kv 11, 12: 3 blocks
-      each); the idle row sits at position 0 and reads block 0."""
+      each); the idle row sits at position 0 and reads block 0: each
+      call runs one iteration a row, 2 x 2 x 8 slots."""
     eng = _engine(tiny)
+    assert eng._chunk_pages == 8
     c0 = _counters(eng)
     _serve_one(eng, list(range(1, 11)), 3)
     d = {k: v - c0[k] for k, v in _counters(eng).items()}
@@ -121,23 +126,16 @@ def test_dispatch_counters_match_hand_counts(tiny):
     assert d["prefill_tokens"] == 9            # leaves the last token out
     assert d["prefill_token_slots"] == 3 * 2 * 4
     assert d["prefill_attn_live_steps"] == 1 + 2 + 3
-    assert d["prefill_attn_grid_steps"] == 3 * 2 * 8
+    assert d["prefill_attn_grid_steps"] == 3 * 8
     assert d["decode_attn_live_steps"] == 2 * (3 + 1)
     assert d["decode_attn_grid_steps"] == 2 * 2 * 8
 
 
-@pytest.mark.parametrize("pattern", [
-    # (q_starts, kv_lens, query tokens per row, window)
-    ([0, 8, 22, 12], [1, 9, 23, 13], 1, 0),       # decode; row 0 idle
-    ([0, 4, 0, 8], [4, 8, 0, 10], 4, 0),          # prefill; row 2 idle
-    ([20, 16, 0, 0], [24, 20, 3, 0], 4, 6),       # sliding window
-], ids=["decode", "prefill", "window"])
-def test_grid_steps_equals_kernel_visits(pattern):
-    """The host count of live grid steps is the sum of the per-row block
-    visits the kernel reports (interpret mode), and the grid launched is
-    rows x table width."""
-    q_starts, kv_lens, C, window = pattern
-    B, NB, bs, P, KH, H, D = 4, 6, 4, 32, 2, 4, 8
+def _visits(q_starts, kv_lens, C, window, NB, bs, P=40):
+    """(per-row page visits the kernel reports in interpret mode, pages a
+    chunk) of one call over a random ``NB``-wide table of ``bs``-token
+    blocks."""
+    B, KH, H, D = len(kv_lens), 2, 4, 8
     rng = np.random.default_rng(0)
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
     q = jax.random.normal(ks[0], (B, C, H, D), jnp.float32)
@@ -148,10 +146,49 @@ def test_grid_steps_equals_kernel_visits(pattern):
         q, k_pool, v_pool, jnp.asarray(tables),
         jnp.asarray(q_starts, jnp.int32), jnp.asarray(kv_lens, jnp.int32),
         window=window, scale=None, interpret=True)
-    live, launched = grid_steps(np.array(kv_lens), np.array(q_starts), NB,
-                                bs, window)
-    assert live == int(np.asarray(visits)[:, 0].sum())
-    assert launched == B * NB
+    return np.asarray(visits)[:, 0], chunk_pages(k_pool, v_pool, NB)
+
+
+@pytest.mark.parametrize("pattern", [
+    # (q_starts, kv_lens, query tokens per row, window, page slots
+    # launched): 6-block tables of 4-token blocks, chunks of 6 pages
+    ([0, 8, 22, 12], [1, 9, 23, 13], 1, 0, 4 * 6),   # decode; row 0 idle
+    ([0, 4, 0, 8], [4, 8, 0, 10], 4, 0, 3 * 6),      # prefill; row 2 idle
+    ([20, 16, 0, 0], [24, 20, 3, 0], 4, 6, 3 * 6),   # sliding window
+], ids=["decode", "prefill", "window"])
+def test_grid_steps_equals_kernel_visits(pattern):
+    """The host count of live pages is the sum of the per-row page visits
+    the kernel reports (interpret mode), and the page slots launched are
+    one chunk (6 pages: 128 keys would be 32 blocks, the table caps it)
+    per iteration, one iteration per row with a live page:
+
+    - decode: kv 1, 9, 23, 13 are 1, 3, 6, 4 live pages: 4 iterations;
+    - prefill: kv 4, 8, 0, 10 are 1, 2, 0, 3 pages: the idle row runs no
+      iteration, 3 in all;
+    - window 6: the oldest queries at 20 and 16 see from 15 and 11, so
+      pages 3-5 and 2-4 of kv 24 and 20 are live, 1 page of kv 3 and
+      none of kv 0: 3 iterations."""
+    q_starts, kv_lens, C, window, launched = pattern
+    visits, pages = _visits(q_starts, kv_lens, C, window, NB=6, bs=4)
+    assert pages == 6
+    assert grid_steps(np.array(kv_lens), np.array(q_starts), pages, 4,
+                      window) == (int(visits.sum()), launched)
+
+
+def test_launched_page_slots_follow_live_length_not_table_width():
+    """The same lengths on a 6-wide and a 64-wide table of 32-token
+    blocks (chunks of 4 pages, 128 keys) launch the same page slots: kv
+    1, 40, 130 and 0 are 1, 2, 5 and 0 live pages, 1 + 1 + 2 iterations
+    of 4 slots each, whatever the table's width."""
+    q_starts, kv_lens = [0, 39, 129, 0], [1, 40, 130, 0]
+    counts = []
+    for NB in (6, 64):
+        visits, pages = _visits(q_starts, kv_lens, 1, 0, NB=NB, bs=32)
+        assert pages == 4
+        np.testing.assert_array_equal(visits, [1, 2, 5, 0])
+        counts.append(grid_steps(np.array(kv_lens), np.array(q_starts),
+                                 pages, 32))
+    assert counts == [(8, 4 * 4)] * 2
 
 
 # ---------------------------------------------------------------------------

@@ -127,15 +127,37 @@ def test_scheduler_preempts_youngest_on_pool_exhaustion():
 # Paged attention kernel vs reference
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("B,H,KH,D,DV,bs,NB,window,dtype", [
-    (2, 4, 2, 16, 16, 8, 4, 0, jnp.float32),
-    (3, 4, 1, 32, 16, 4, 8, 0, jnp.float32),
-    (1, 8, 8, 16, 16, 16, 2, 0, jnp.float32),
-    (2, 4, 2, 16, 16, 8, 4, 5, jnp.float32),
-    (2, 2, 2, 32, 32, 8, 4, 0, jnp.bfloat16),
+def _chunk_edges(kp, vp, NB):
+    """kv lengths at the edges of the kernel's chunks: 1, one block, a
+    chunk less one, a chunk, a chunk and one, the whole table."""
+    from repro.kernels.paged_attention.ops import chunk_pages
+    bs = kp.shape[-2]
+    keys = chunk_pages(kp, vp, NB) * bs
+    return np.array([1, bs, keys - 1, keys, keys + 1, NB * bs], np.int32)
+
+
+@pytest.mark.parametrize("B,H,KH,D,DV,bs,NB,window,dtype,lens", [
+    pytest.param(2, 4, 2, 16, 16, 8, 4, 0, jnp.float32, "random",
+                 id="2-4-2-16-16-8-4-0-float32"),
+    pytest.param(3, 4, 1, 32, 16, 4, 8, 0, jnp.float32, "random",
+                 id="3-4-1-32-16-4-8-0-float32"),
+    pytest.param(1, 8, 8, 16, 16, 16, 2, 0, jnp.float32, "random",
+                 id="1-8-8-16-16-16-2-0-float32"),
+    pytest.param(2, 4, 2, 16, 16, 8, 4, 5, jnp.float32, "random",
+                 id="2-4-2-16-16-8-4-5-float32"),
+    pytest.param(2, 2, 2, 32, 32, 8, 4, 0, jnp.bfloat16, "random",
+                 id="2-2-2-32-32-8-4-0-bfloat16"),
+    # chunk edges (16 pages of 8 tokens): a value head per lane group,
+    # two 64-wide heads sharing one, and a sliding window
+    pytest.param(6, 4, 2, 16, 128, 8, 40, 0, jnp.bfloat16, "edges",
+                 id="edges-dense-bfloat16"),
+    pytest.param(6, 8, 4, 16, 64, 8, 40, 0, jnp.bfloat16, "edges",
+                 id="edges-pruned-bfloat16"),
+    pytest.param(6, 4, 2, 16, 16, 8, 40, 37, jnp.float32, "edges",
+                 id="edges-window-float32"),
 ])
 def test_paged_attention_kernel_parity(B, H, KH, D, DV, bs, NB, window,
-                                       dtype):
+                                       dtype, lens):
     P = B * NB + 1
     q = jnp.asarray(rng.normal(size=(B, H, D)), dtype)
     kp = jnp.asarray(rng.normal(size=(P, KH, bs, D)), dtype)
@@ -143,7 +165,8 @@ def test_paged_attention_kernel_parity(B, H, KH, D, DV, bs, NB, window,
     # non-contiguous tables: shuffled pool blocks, none uses block 0
     tables = jnp.asarray(
         1 + rng.permutation(B * NB).reshape(B, NB), jnp.int32)
-    lens = jnp.asarray(rng.integers(1, NB * bs + 1, size=(B,)), jnp.int32)
+    lens = jnp.asarray(_chunk_edges(kp, vp, NB) if lens == "edges" else
+                       rng.integers(1, NB * bs + 1, size=(B,)), jnp.int32)
     out = paged_attention(q, kp, vp, tables, lens, window=window,
                           use_kernel=True)
     ref = paged_attention_reference(q, kp, vp, tables, lens, window=window)
@@ -197,6 +220,7 @@ def test_paged_attention_window_skips_left_of_window():
     (2, 4, 4, 2, 16, 8, 4),
     (3, 7, 4, 1, 32, 4, 8),
     (1, 16, 8, 8, 16, 16, 2),
+    (3, 8, 4, 2, 128, 8, 40),          # several 16-page chunks a row
 ])
 def test_paged_prefill_kernel_parity(B, C, H, KH, D, bs, NB):
     """Prefill-aware masking: C queries per sequence at absolute positions

@@ -397,6 +397,19 @@ def _drive_migration(e1, e2, prompts, migrate_at, faults1=None,
     return out, migrated
 
 
+def test_migration_hands_back_holds_of_an_emptied_engine(engine_pair):
+    """A pool hold injected at step 5 for 3 steps, then every request
+    migrates away at step 8: the emptied engine is never stepped again,
+    so exporting its last request must hand the hold back, or it reads
+    as leaked blocks (a counterexample hypothesis found)."""
+    e1, e2, prompts, ref = engine_pair
+    hold = Fault(kind="alloc_hold", step=5, hold_steps=3)
+    out, migrated = _drive_migration(
+        e1, e2, prompts, [8], faults1=FaultInjector([hold], seed=0),
+        faults2=FaultInjector([], seed=1))
+    assert migrated and out == ref
+
+
 # ---------------------------------------------------------------------------
 # hypothesis variants (preferred when available)
 # ---------------------------------------------------------------------------

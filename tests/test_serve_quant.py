@@ -28,7 +28,6 @@ from repro.kernels.paged_attention import (
     dequantize, is_quantized, paged_attention, paged_attention_reference,
     paged_prefill_attention, paged_prefill_attention_reference, pool_dtype,
     quantize, v_rows)
-from repro.kernels.paged_attention.paged_attention import _block_live
 from repro.launch.serve import generate
 from repro.models import build
 from repro.serve import Engine, ServeConfig
@@ -114,10 +113,9 @@ def test_quantized_prefill_kernel_parity(dtype_name, B, C, H, KH, D, bs,
 
 
 def test_quantized_window_skip_visit_counters():
-    """The sliding-window DMA skip and the compute skip share one
-    liveness predicate: the visit counters must equal the analytic count
-    of window-live blocks exactly — a block the counter says was skipped
-    is a block whose DMA degraded to the null block."""
+    """The kernel loops over a row's window-live pages only, fetching and
+    computing those and no others: the visit counters must equal the
+    analytic count of window-live blocks exactly."""
     B, H, KH, D, bs, NB = 2, 2, 2, 16, 4, 8
     P = B * NB + 1
     q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
@@ -134,8 +132,9 @@ def test_quantized_window_skip_visit_counters():
                                         v_scale=sv)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=1e-5, atol=1e-5)
-        expect = [sum(bool(_block_live(j, L, L - 1, window=window,
-                                       block_size=bs))
+        # a page is live when it holds a key at or before the query (at
+        # L - 1) and right of its window's left edge (L - 1 - window)
+        expect = [sum(j * bs < L and j * bs + bs - 1 > L - 1 - window
                       for j in range(NB)) for L in lens]
         np.testing.assert_array_equal(
             np.asarray(visits), np.tile(np.asarray(expect)[:, None], KH))
